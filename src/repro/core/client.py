@@ -356,6 +356,17 @@ class LocalServer:
                 self.synced_files[f] = self.last_sync_ts
 
 
+def _covers_block(patches: List[Tuple[int, bytes]], block_size: int) -> bool:
+    """Whether the (offset, bytes) patches together cover every byte of
+    the block: a union of intervals, merged in offset order."""
+    reach = 0
+    for off, data in sorted(patches, key=lambda p: p[0]):
+        if off > reach:
+            return False
+        reach = max(reach, off + len(data))
+    return reach >= block_size
+
+
 @dataclass
 class _TxnFile:
     fid: FileId
@@ -1008,19 +1019,12 @@ class Transaction:
                 ent = self.local.cache.get(w.key)
                 if w.key in self.reads and ent is not None and ent.version == self.reads[w.key]:
                     self.local._put(w.key, wts, w.apply_to(ent.data, self.block_size))
+                elif _covers_block(w.patches, self.block_size):
+                    self.local._put(
+                        w.key, wts, w.apply_to(b"", self.block_size)
+                    )
                 else:
-                    fully_covered = w.apply_to(b"", self.block_size)
-                    covered = bytearray(self.block_size)
-                    n = 0
-                    for off, data in w.patches:
-                        for i in range(off, min(off + len(data), self.block_size)):
-                            if not covered[i]:
-                                covered[i] = 1
-                                n += 1
-                    if n == self.block_size:
-                        self.local._put(w.key, wts, fully_covered)
-                    else:
-                        self.local.cache.pop(w.key, None)
+                    self.local.cache.pop(w.key, None)
             # NOTE: last_sync_ts must NOT advance here — other clients may
             # have committed between our begin and our commit, and we have
             # not seen their cache updates (snapshot reads rely on this).

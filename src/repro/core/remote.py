@@ -288,8 +288,19 @@ class _RemoteCore(BackendAPI):
     def fetch_block(self, key: BlockKey, at_ts=None):
         return self._call(*self._enc_fetch_block(key, at_ts))
 
+    def _fetch_batches(self, keys: List[BlockKey]) -> Tuple[List[int], int]:
+        """Start offsets that cut ``keys`` into batches whose reply frame
+        stays well inside ``wire.MAX_BODY``."""
+        step = max(1, wire.MAX_BODY // 4 // self.block_size)
+        return list(range(0, len(keys), step)) or [0], step
+
     def fetch_blocks(self, keys: List[BlockKey], at_ts=None):
-        return self._call(*self._enc_fetch_blocks(keys, at_ts))
+        starts, step = self._fetch_batches(keys)
+        out = []
+        for lo in starts:
+            out.extend(self._call(
+                *self._enc_fetch_blocks(keys[lo:lo + step], at_ts)))
+        return out
 
     def fetch_blocks_into(self, keys: List[BlockKey], at_ts, sink):
         """Zero-copy ``fetch_blocks``: block payloads decode straight out
@@ -303,17 +314,23 @@ class _RemoteCore(BackendAPI):
         decoded without the sink (reader replaced by a redial, or an
         error reply) the future fails typed or the entries come back as
         plain bytes; callers must accept both."""
-        mt, body, decode = self._enc_fetch_blocks(keys, at_ts)
-        counter = [0]
+        starts, step = self._fetch_batches(keys)
+        out = []
+        for lo in starts:
+            batch = keys[lo:lo + step]
+            mt, body, decode = self._enc_fetch_blocks(batch, at_ts)
+            counter = [0]
 
-        def wire_sink(nbytes):
-            i = counter[0]
-            counter[0] += 1
-            if i >= len(keys):
-                return None
-            return sink(i, nbytes)
+            def wire_sink(nbytes, lo=lo, n=len(batch), counter=counter):
+                i = counter[0]
+                counter[0] += 1
+                if i >= n:
+                    return None
+                return sink(lo + i, nbytes)
 
-        return self.submit_frame(mt, body, decode, sink=wire_sink).result()
+            out.extend(
+                self.submit_frame(mt, body, decode, sink=wire_sink).result())
+        return out
 
     def fetch_meta(self, fid: FileId, at_ts=None):
         return self._call(*self._enc_fetch_meta(fid, at_ts))

@@ -15,6 +15,11 @@ block-granular machinery gives us, for free:
     paper's TPC-C warehouses: disjoint-block commits interleave without
     locks; conflicting commits abort and retry.
 
+A save is either in place (same name, only changed blocks rewritten) or
+chained: a save under a new name with ``base=<earlier name>`` writes only
+its changed blocks, lists them per leaf in its ``.meta``, and ``load``
+reads every other block from the base chain.
+
 The on-device companion is the ``block_delta`` Pallas kernel, which computes
 per-block dirty masks / int8-quantized deltas so only changed blocks cross
 the wire (gradient/update compression keyed to block layout).
@@ -23,16 +28,22 @@ from __future__ import annotations
 
 import json
 import weakref
-import zlib
-from typing import Any, Dict, Iterable, List, Optional, Tuple
+from dataclasses import dataclass, field
+from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
 
 from repro.core.arena import BlockArena, default_arena
 from repro.core.posix import FaaSFS, O_CREAT, O_TRUNC
 from repro.core.types import TENSOR_BLOCK_BYTES, NotFound
+from repro.core.wire import MAX_BODY
 
 PyTree = Any
+
+#: write-set bytes one save transaction carries. A commit rides one wire
+#: frame, whose body is capped at ``MAX_BODY``, so ``save_tree`` splits a
+#: larger save into several transactions.
+TXN_WRITE_BYTES = MAX_BODY // 4
 
 
 def flatten_with_names(tree: PyTree, prefix: str = "") -> List[Tuple[str, np.ndarray]]:
@@ -49,8 +60,118 @@ def flatten_with_names(tree: PyTree, prefix: str = "") -> List[Tuple[str, np.nda
     return out
 
 
-def _leaf_bytes(arr: np.ndarray) -> bytes:
-    return np.ascontiguousarray(arr).tobytes()
+def _leaf_u8(arr: np.ndarray) -> np.ndarray:
+    """The leaf's raw bytes as a flat uint8 array (no copy when the leaf
+    is already C-contiguous)."""
+    return np.ascontiguousarray(arr).reshape(-1).view(np.uint8)
+
+
+def changed_blocks(new: np.ndarray, old: np.ndarray,
+                   block_bytes: int) -> Optional[List[int]]:
+    """Exact byte compare: indices of ``block_bytes`` blocks over the raw
+    leaf bytes where ``new`` differs from ``old``; ``None`` when dtype or
+    shape differ (the whole leaf must be written)."""
+    new, old = np.asarray(new), np.asarray(old)
+    if new.dtype != old.dtype or new.shape != old.shape:
+        return None
+    a, b = _leaf_u8(new), _leaf_u8(old)
+    full = len(a) // block_bytes
+    cut = full * block_bytes
+    diff = np.any(
+        a[:cut].reshape(full, block_bytes) != b[:cut].reshape(full, block_bytes),
+        axis=1,
+    )
+    out = [int(i) for i in np.flatnonzero(diff)]
+    if cut < len(a) and not np.array_equal(a[cut:], b[cut:]):
+        out.append(full)
+    return out
+
+
+def _runs(blocks: Iterable[int]) -> List[Tuple[int, int]]:
+    """Sorted block indices as half-open ``[start, stop)`` runs."""
+    out: List[Tuple[int, int]] = []
+    for b in sorted(set(int(b) for b in blocks)):
+        if out and out[-1][1] == b:
+            out[-1] = (out[-1][0], b + 1)
+        else:
+            out.append((b, b + 1))
+    return out
+
+
+@dataclass
+class SavePlan:
+    """What a save writes: per leaf, the byte spans of its blocks to write,
+    and the ``.meta`` that makes the save visible to ``load``."""
+
+    meta: Dict[str, Any]
+    #: (leaf name, leaf bytes, [(start, stop) byte spans]); an empty span
+    #: list still creates/sizes the file
+    leaves: List[Tuple[str, np.ndarray, List[Tuple[int, int]]]] = \
+        field(default_factory=list)
+    stats: Dict[str, int] = field(default_factory=dict)
+
+    def parts(self, part_bytes: int) -> List[List[Tuple[str, np.ndarray, int, int]]]:
+        """Split the writes into transactions of at most ``part_bytes``
+        (a span is cut at block boundaries; one block never splits)."""
+        bb = self.meta["block_bytes"]
+        step = max(bb, part_bytes // bb * bb)
+        parts: List[List[Tuple[str, np.ndarray, int, int]]] = [[]]
+        used = 0
+        for lname, raw, spans in self.leaves:
+            if not spans:
+                parts[-1].append((lname, raw, 0, 0))
+            for lo, hi in spans:
+                while lo < hi:
+                    if used >= step:
+                        parts.append([])
+                        used = 0
+                    n = min(hi - lo, step - used)
+                    parts[-1].append((lname, raw, lo, lo + n))
+                    used += -(-n // bb) * bb
+                    lo += n
+        return parts
+
+
+def plan_save(
+    tree: PyTree,
+    *,
+    baseline: Optional[Dict[str, np.ndarray]] = None,
+    block_bytes: int = TENSOR_BLOCK_BYTES,
+    dirty_blocks: Optional[Dict[str, Iterable[int]]] = None,
+    base: Optional[str] = None,
+) -> SavePlan:
+    """Plan a save (see ``TensorStore.save`` for the arguments)."""
+    leaves = flatten_with_names(tree)
+    meta: Dict[str, Any] = {"leaves": [], "block_bytes": block_bytes}
+    if base is not None:
+        meta["base"] = base
+    plan = SavePlan(meta, stats={"leaves": len(leaves), "bytes_total": 0,
+                                 "bytes_written": 0, "blocks_written": 0})
+    for lname, arr in leaves:
+        raw = _leaf_u8(arr)
+        n = len(raw)
+        entry: Dict[str, Any] = {"name": lname, "dtype": str(arr.dtype),
+                                 "shape": list(arr.shape)}
+        blocks: Optional[List[int]] = None
+        if dirty_blocks and lname in dirty_blocks:
+            blocks = sorted(set(int(b) for b in dirty_blocks[lname]))
+        elif baseline is not None and lname in baseline:
+            blocks = changed_blocks(arr, baseline[lname], block_bytes)
+        if blocks is None:
+            spans = [(0, n)] if n else []
+            nblocks = -(-n // block_bytes)
+        else:
+            spans = [(lo * block_bytes, min(hi * block_bytes, n))
+                     for lo, hi in _runs(blocks) if lo * block_bytes < n]
+            nblocks = sum(-(-(hi - lo) // block_bytes) for lo, hi in spans)
+            if base is not None:
+                entry["blocks"] = blocks
+        meta["leaves"].append(entry)
+        plan.leaves.append((lname, raw, spans))
+        plan.stats["bytes_total"] += n
+        plan.stats["bytes_written"] += sum(hi - lo for lo, hi in spans)
+        plan.stats["blocks_written"] += nblocks
+    return plan
 
 
 class TensorStore:
@@ -85,84 +206,100 @@ class TensorStore:
         baseline: Optional[Dict[str, np.ndarray]] = None,
         block_bytes: int = TENSOR_BLOCK_BYTES,
         dirty_blocks: Optional[Dict[str, Iterable[int]]] = None,
+        base: Optional[str] = None,
     ) -> Dict[str, int]:
-        """Write a pytree. With ``baseline`` (previous leaf arrays), only
-        blocks whose bytes changed are written — the delta-commit path.
+        """Write a pytree in this transaction. With ``baseline`` (previous
+        leaf arrays), only blocks whose bytes changed are written — the
+        delta-commit path.
 
         ``dirty_blocks`` short-circuits the byte-compare: for a leaf
         listed there, ONLY the given block indices are written (exact
         new bytes — the mask is a detector, never a value source), so a
-        kernel-computed dirty mask (``compute_block_delta``/``pack_dirty``)
-        drives the write set without touching the clean bytes at all.
-        Leaves absent from the dict fall back to baseline comparison.
+        kernel-computed dirty mask (``compute_block_delta``) drives the
+        write set without touching the clean bytes at all. Leaves absent
+        from the dict fall back to baseline comparison.
+
+        Without ``base`` the unwritten blocks are whatever ``name`` held
+        before (in place). With ``base`` (an earlier save's name) the
+        save is chained: ``load`` reads every block this save did not
+        write from ``base``.
 
         Returns stats: leaves, bytes_total, bytes_written, blocks_written.
         """
-        leaves = flatten_with_names(tree)
-        meta = {
-            "leaves": [
-                {"name": n, "dtype": str(a.dtype), "shape": list(a.shape)}
-                for n, a in leaves
-            ],
-            "block_bytes": block_bytes,
-        }
-        stats = {"leaves": len(leaves), "bytes_total": 0, "bytes_written": 0,
-                 "blocks_written": 0}
-        for lname, arr in leaves:
-            raw = _leaf_bytes(arr)
-            stats["bytes_total"] += len(raw)
-            path = self._leaf_path(name, lname)
-            fd = self.fs.open(path, O_CREAT)
-            mask = dirty_blocks.get(lname) if dirty_blocks else None
-            base_raw = None
-            if mask is None and baseline is not None and lname in baseline:
-                base_raw = _leaf_bytes(baseline[lname])
-                if len(base_raw) != len(raw):
-                    base_raw = None
-            if mask is not None:
-                for bi in sorted(set(int(b) for b in mask)):
-                    off = bi * block_bytes
-                    chunk = raw[off : off + block_bytes]
-                    if not chunk:
-                        continue
-                    self.fs.pwrite(fd, chunk, off)
-                    stats["bytes_written"] += len(chunk)
-                    stats["blocks_written"] += 1
-                self.fs.ftruncate(fd, len(raw))
-            elif base_raw is None:
-                self.fs.pwrite(fd, raw, 0)
-                stats["bytes_written"] += len(raw)
-                stats["blocks_written"] += -(-len(raw) // block_bytes)
-            else:
-                for off in range(0, len(raw), block_bytes):
-                    chunk = raw[off : off + block_bytes]
-                    if chunk != base_raw[off : off + block_bytes]:
-                        self.fs.pwrite(fd, chunk, off)
-                        stats["bytes_written"] += len(chunk)
-                        stats["blocks_written"] += 1
-                self.fs.ftruncate(fd, len(raw))
+        plan = plan_save(tree, baseline=baseline, block_bytes=block_bytes,
+                         dirty_blocks=dirty_blocks, base=base)
+        for part in plan.parts(max(plan.stats["bytes_written"], 1)):
+            self.write_part(name, part)
+        self.write_meta(name, plan.meta)
+        return plan.stats
+
+    def write_part(self, name: str,
+                   part: List[Tuple[str, np.ndarray, int, int]]) -> None:
+        """Write one part of a ``SavePlan`` (leaf byte spans)."""
+        for lname, raw, lo, hi in part:
+            fd = self.fs.open(self._leaf_path(name, lname), O_CREAT)
+            if hi > lo:
+                self.fs.pwrite(fd, memoryview(raw)[lo:hi], lo)
+            self.fs.ftruncate(fd, len(raw))
             self.fs.close(fd)
+
+    def write_meta(self, name: str, meta: Dict[str, Any]) -> None:
         mfd = self.fs.open(self._meta_path(name), O_CREAT | O_TRUNC)
         self.fs.write(mfd, json.dumps(meta).encode())
         self.fs.close(mfd)
-        return stats
 
     # ------------------------------------------------------------------ #
+    def read_meta(self, name: str) -> Dict[str, Any]:
+        mfd = self.fs.open(self._meta_path(name))
+        size = self.fs.fstat(mfd)["st_size"]
+        meta = json.loads(self.fs.pread(mfd, size, 0))
+        self.fs.close(mfd)
+        return meta
+
+    def _sources(self, name: str, leaf: Dict[str, Any], nbytes: int,
+                 metas: Dict[str, Dict[str, Any]]) -> List[Tuple[str, List[Tuple[int, int]]]]:
+        """Where each block of ``leaf`` lives along the base chain:
+        ``[(save name, [(start, stop) byte spans])]``, newest first."""
+        bb = metas[name]["block_bytes"]
+        need = set(range(-(-nbytes // bb)))
+        out = []
+        cur, entry = name, leaf
+        seen = {name}
+        while need:
+            have = entry.get("blocks")
+            take = need if have is None else need.intersection(have)
+            if take:
+                out.append((cur, [(lo * bb, min(hi * bb, nbytes))
+                                  for lo, hi in _runs(take)]))
+                need = need - take
+            if not need:
+                break
+            cur = metas[cur].get("base")
+            if cur is None or cur in seen:
+                raise NotFound(f"{name}: blocks of {leaf['name']} in no base")
+            seen.add(cur)
+            if cur not in metas:
+                metas[cur] = self.read_meta(cur)
+            entry = next((l for l in metas[cur]["leaves"]
+                          if l["name"] == leaf["name"]), None)
+            if entry is None:
+                raise NotFound(f"{cur}: leaf {leaf['name']} missing")
+        return out
+
     def load(self, name: str, *, zero_copy: bool = False) -> Dict[str, np.ndarray]:
         """Read all leaves as a flat {name: array} dict.
 
         ``zero_copy=True`` is the arena path: the ``.meta`` layout keys a
         tensor-sized span read per leaf — every block of the leaf goes
-        out in ONE ``fetch_blocks`` round trip and each payload lands
-        directly in the leaf's arena buffer (no per-block ``bytes``, no
-        assembly copy, no ``.copy()``). Returned arrays are READONLY
-        views over sealed arena buffers; they stay valid as long as any
-        view is alive and the backing buffer is recycled when the last
-        one dies. Callers that need to mutate must ``.copy()``."""
-        mfd = self.fs.open(self._meta_path(name))
-        size = self.fs.fstat(mfd)["st_size"]
-        meta = json.loads(self.fs.pread(mfd, size, 0))
-        self.fs.close(mfd)
+        out in ONE ``fetch_blocks`` round trip (one per run of blocks
+        when the save is chained) and each payload lands directly in the
+        leaf's arena buffer (no per-block ``bytes``, no assembly copy, no
+        ``.copy()``). Returned arrays are READONLY views over sealed
+        arena buffers; they stay valid as long as any view is alive and
+        the backing buffer is recycled when the last one dies. Callers
+        that need to mutate must ``.copy()``."""
+        metas = {name: self.read_meta(name)}
+        meta = metas[name]
         # tensor-sized readahead: the meta layout names every leaf, so
         # one lookup_many primes the whole checkpoint's name->fid map
         # before any data moves (readdir-free, single round trip)
@@ -176,19 +313,22 @@ class TensorStore:
             txn = self.fs.txn
             sunk0, copied0 = txn.bytes_sunk, txn.bytes_copied_into
         for leaf in meta["leaves"]:
-            path = self._leaf_path(name, leaf["name"])
             dt = np.dtype(leaf["dtype"])
-            fd = self.fs.open(path)
-            n = self.fs.fstat(fd)["st_size"]
+            count = int(np.prod(leaf["shape"], dtype=np.int64)) \
+                if leaf["shape"] else 1
+            n = count * dt.itemsize
+            srcs = self._sources(name, leaf, n, metas)
             if arena is not None:
                 buf = arena.alloc(n, round_to=self.fs.txn.block_size)
-                # block-aligned capacity: every block in the span is a
-                # full-size sink destination, incl. the ragged tail
-                self.fs.pread_into(fd, n, 0, buf.view(0, buf.capacity))
-                self.fs.close(fd)
+                for src, spans in srcs:
+                    fd = self.fs.open(self._leaf_path(src, leaf["name"]))
+                    for lo, hi in spans:
+                        # block-aligned capacity: every block in the span
+                        # is a full-size sink destination, incl. the tail
+                        self.fs.pread_into(fd, hi - lo, lo,
+                                           buf.view(lo, buf.capacity - lo))
+                    self.fs.close(fd)
                 mv = buf.seal()
-                count = int(np.prod(leaf["shape"], dtype=np.int64)) \
-                    if leaf["shape"] else 1
                 root = np.frombuffer(mv, dtype=dt, count=count)
                 # recycle the buffer when the last aliasing view dies:
                 # every numpy view of ``root`` keeps ``root`` alive
@@ -197,11 +337,15 @@ class TensorStore:
                 weakref.finalize(root, buf.release)
                 out[leaf["name"]] = root.reshape(leaf["shape"])
             else:
-                raw = self.fs.pread(fd, n, 0)
-                self.fs.close(fd)
+                data = bytearray(n)
+                for src, spans in srcs:
+                    fd = self.fs.open(self._leaf_path(src, leaf["name"]))
+                    for lo, hi in spans:
+                        data[lo:hi] = self.fs.pread(fd, hi - lo, lo)
+                    self.fs.close(fd)
                 out[leaf["name"]] = np.frombuffer(
-                    raw, dtype=dt
-                ).reshape(leaf["shape"]).copy()
+                    data, dtype=dt, count=count
+                ).reshape(leaf["shape"])
         if arena is not None:
             arena.note_fill(txn.bytes_sunk - sunk0)
             arena.note_copy(txn.bytes_copied_into - copied0)
@@ -209,6 +353,43 @@ class TensorStore:
 
     def exists(self, name: str) -> bool:
         return self.fs.exists(self._meta_path(name))
+
+
+def save_tree(
+    runtime,
+    prefix: str,
+    name: str,
+    tree: PyTree,
+    *,
+    finish: Optional[Callable[[FaaSFS], None]] = None,
+    stats=None,
+    part_bytes: int = TXN_WRITE_BYTES,
+    **plan_kw: Any,
+) -> Dict[str, int]:
+    """``TensorStore.save`` as invocations on ``runtime`` (a
+    ``FunctionRuntime``), in as many transactions as the write set needs
+    at ``part_bytes`` each. The ``.meta`` — and ``finish(fs)``, e.g. a
+    pointer flip — ride the last one, so a save that fits one part is one
+    atomic transaction, and a larger one becomes visible to ``load`` at
+    once when its last part commits. A split save should target a name
+    no reader is loading: its earlier parts overwrite blocks in place.
+    ``stats`` (an ``InvocationStats``) receives the last transaction's.
+    Returns the plan's stats plus ``txns``."""
+    plan = plan_save(tree, **plan_kw)
+    parts = plan.parts(part_bytes)
+
+    def write(fs: FaaSFS, part, last: bool) -> None:
+        store = TensorStore(fs, prefix=prefix)
+        store.write_part(name, part)
+        if last:
+            store.write_meta(name, plan.meta)
+            if finish is not None:
+                finish(fs)
+
+    for i, part in enumerate(parts):
+        last = i == len(parts) - 1
+        runtime.invoke(write, part, last, stats=stats if last else None)
+    return dict(plan.stats, txns=len(parts))
 
 
 def unflatten_like(template: PyTree, flat: Dict[str, np.ndarray]) -> PyTree:

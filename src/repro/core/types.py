@@ -136,6 +136,9 @@ class WriteRecord:
     patches: List[Tuple[int, bytes]] = field(default_factory=list)
 
     def apply_to(self, base: bytes, block_size: int) -> bytes:
+        if len(self.patches) == 1 and self.patches[0][0] == 0 \
+                and len(self.patches[0][1]) == block_size:
+            return bytes(self.patches[0][1])  # whole block: no base merge
         buf = bytearray(base.ljust(block_size, b"\0"))
         for off, data in self.patches:
             buf[off : off + len(data)] = data

@@ -10,27 +10,36 @@ FaaSFS blocks, one grid step per block computes, entirely in VMEM:
 
 The commit path then ships only blocks whose norm clears a threshold, as
 int8 + one fp32 scale — the paper's block-granular cache-update protocol
-doubling as gradient/update compression (DESIGN.md §3).
+doubling as gradient/update compression.
+
+TPU layout: a block of ``block_elems`` values is viewed as a
+``(block_elems // 128, 128)`` slab, so each grid step's tile spans the
+whole of its array's last two dimensions and meets the (8, 128) f32 and
+(32, 128) int8 tiling rules at any block size. The per-block ``norm2``
+and ``scale`` are written broadcast across one 128-lane row rather than
+as scalar VMEM stores, which Mosaic refuses. A slab lives in VMEM whole
+(double-buffered inputs and outputs), so a block must stay well inside
+the scoped VMEM budget: 256 KiB f32 blocks take about 1.5 MiB.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+LANES = 128
 
 
 def _delta_kernel(new_ref, old_ref, q_ref, norm_ref, scale_ref):
-    new = new_ref[...].astype(jnp.float32)      # (1, block)
+    new = new_ref[...].astype(jnp.float32)      # (1, rows, LANES)
     old = old_ref[...].astype(jnp.float32)
     diff = new - old
     norm2 = jnp.sum(diff * diff)
     maxabs = jnp.max(jnp.abs(diff))
     scale = jnp.where(maxabs > 0, maxabs / 127.0, 1.0)
-    q = jnp.clip(jnp.round(diff / scale), -127, 127).astype(jnp.int8)
-    q_ref[...] = q
-    norm_ref[0, 0] = norm2
-    scale_ref[0, 0] = scale
+    q_ref[...] = jnp.clip(jnp.round(diff / scale), -127, 127).astype(jnp.int8)
+    norm_ref[...] = jnp.full(norm_ref.shape, norm2, jnp.float32)
+    scale_ref[...] = jnp.full(scale_ref.shape, scale, jnp.float32)
 
 
 def block_delta(
@@ -41,23 +50,22 @@ def block_delta(
 ):
     """Returns (q int8 (nblocks, block_elems), norm2 (nblocks,), scale (nblocks,))."""
     nb, be = new.shape
+    if be % LANES:
+        raise ValueError(f"block_elems={be} is not a multiple of {LANES}")
+    rows = be // LANES
+    slab = pl.BlockSpec((1, rows, LANES), lambda i: (i, 0, 0))
+    lane = pl.BlockSpec((1, 1, LANES), lambda i: (i, 0, 0))
     q, norm2, scale = pl.pallas_call(
         _delta_kernel,
         grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1, be), lambda i: (i, 0)),
-            pl.BlockSpec((1, be), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, be), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
+        in_specs=[slab, slab],
+        out_specs=[slab, lane, lane],
         out_shape=[
-            jax.ShapeDtypeStruct((nb, be), jnp.int8),
-            jax.ShapeDtypeStruct((nb, 1), jnp.float32),
-            jax.ShapeDtypeStruct((nb, 1), jnp.float32),
+            jax.ShapeDtypeStruct((nb, rows, LANES), jnp.int8),
+            jax.ShapeDtypeStruct((nb, 1, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((nb, 1, LANES), jnp.float32),
         ],
         interpret=interpret,
-    )(new, old)
-    return q, norm2[:, 0], scale[:, 0]
+        name="block_delta",
+    )(new.reshape(nb, rows, LANES), old.reshape(nb, rows, LANES))
+    return q.reshape(nb, be), norm2[:, 0, 0], scale[:, 0, 0]

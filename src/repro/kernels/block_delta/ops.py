@@ -32,9 +32,12 @@ def pack_dirty(
 
 
 def blockify(flat: np.ndarray, block_elems: int) -> np.ndarray:
-    """Pad a flat array to a whole number of blocks and reshape."""
+    """Pad a flat array to a whole number of blocks and reshape (a view
+    when no padding is needed)."""
     n = len(flat)
     nb = -(-n // block_elems)
+    if n == nb * block_elems:
+        return flat.reshape(nb, block_elems)
     out = np.zeros((nb * block_elems,), flat.dtype)
     out[:n] = flat
     return out.reshape(nb, block_elems)

@@ -73,7 +73,7 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, *, overrides=None,
         compiled = lowered.compile()
         t_compile = time.time() - t0
         ma = compiled.memory_analysis()
-        ca = hlo_analysis.xla_cost(compiled)
+        ca = compiled.cost_analysis()
         text = compiled.as_text()
         ana = hlo_analysis.analyze_text(text)
         rec.update(

@@ -53,16 +53,6 @@ class Shape:
         return self.elems * DTYPE_BYTES.get(self.dtype, 4)
 
 
-def xla_cost(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` normalized across JAX generations:
-    newer JAX returns a dict, older releases a one-element list of dicts
-    (one per program)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def parse_type(text: str) -> List[Shape]:
     """Parse 'f32[4,8]{1,0}' or '(f32[2], bf16[3,4])' into Shape list."""
     shapes = []

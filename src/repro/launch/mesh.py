@@ -25,14 +25,9 @@ TPU_XLA_FLAGS = (
 
 
 def _make_mesh(shape, axes) -> jax.sharding.Mesh:
-    """jax.make_mesh across JAX generations: ``axis_types`` only exists
-    where ``jax.sharding.AxisType`` does (newer JAX); older releases take
-    only (shape, axes) and every axis is implicitly Auto."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

@@ -120,25 +120,6 @@ def moe_ffn(
 # --------------------------------------------------------------------------- #
 # §Perf: expert-parallel MoE via shard_map all-to-all
 # --------------------------------------------------------------------------- #
-def _shard_map(f, *, mesh, in_specs, out_specs):
-    """jax.shard_map across JAX generations: new JAX exposes it at the top
-    level (with ``check_vma``); older releases only have
-    ``jax.experimental.shard_map`` (with ``check_rep``). Semantics are
-    identical for our use — both checks are disabled because the combine
-    emits an unreplicated scalar aux loss."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as legacy_shard_map
-
-    return legacy_shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )
-
-
 def moe_ffn_a2a(
     x: jax.Array,
     router: jax.Array,
@@ -237,11 +218,13 @@ def moe_ffn_a2a(
     bspec = tuple(batch_axes) if batch_axes else None
     x_spec = PartitionSpec(bspec, seq_axis, None)
     w_spec = PartitionSpec(model_axis, None, None)
-    out, aux = _shard_map(
+    # replication check off: the combine emits an unreplicated scalar aux loss
+    out, aux = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(x_spec, PartitionSpec(None, None), w_spec, w_spec,
                   PartitionSpec(model_axis, None, None)),
         out_specs=(x_spec, PartitionSpec()),
+        check_vma=False,
     )(x, router, wi, wg, wo)
     return out, aux

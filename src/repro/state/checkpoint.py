@@ -4,12 +4,15 @@ Checkpoints are FaaSFS state objects:
 
   * ``save`` runs as ONE transaction — a checkpoint is atomically visible or
     not at all (no torn checkpoints on worker failure; the paper's atomic
-    commit applied to training state),
+    commit applied to training state). A save whose write set exceeds
+    ``TXN_WRITE_BYTES`` commits its blocks in several transactions into
+    its own ``step_N`` directory and flips ``latest`` in the last one, so
+    it is still seen whole or not at all,
   * consecutive saves ship only dirty blocks: the ``block_delta`` kernel
-    (or an exact numpy fallback) flags dirty blocks against the previous
+    (or the exact numpy compare) flags dirty blocks against the previous
     step's baseline, and ``TensorStore.save`` writes ONLY those blocks'
-    exact new bytes — checkpoint cost scales with the update rate, not
-    the parameter count,
+    exact new bytes, chained to the previous step — checkpoint cost
+    scales with the update rate, not the parameter count,
   * ``restore`` pins a snapshot timestamp (multiversion read) so a restore
     is consistent even while training keeps committing, and loads through
     the zero-copy arena path (``TensorStore.load(zero_copy=True)``),
@@ -32,9 +35,16 @@ import numpy as np
 from repro.core.client import LocalServer
 from repro.core.posix import FaaSFS, O_CREAT, O_TRUNC
 from repro.core.runtime import FunctionRuntime, InvocationStats, runtime_for
-from repro.core.tensorstate import TensorStore, flatten_with_names, unflatten_like
+from repro.core.tensorstate import (
+    TensorStore, changed_blocks, flatten_with_names, save_tree, unflatten_like,
+)
 
 PyTree = Any
+
+
+#: consecutive delta saves before the next save is written in full, so a
+#: restore walks a bounded chain of bases
+DELTA_CHAIN_MAX = 8
 
 
 @dataclass
@@ -45,6 +55,10 @@ class SaveInfo:
     bytes_written: int
     blocks_written: int
     wall_s: float
+    #: transactions the save took (more than one past ``TXN_WRITE_BYTES``)
+    txns: int = 1
+    #: per-leaf dirty block indices a delta save wrote; ``None`` when full
+    dirty: Optional[Dict[str, List[int]]] = None
 
 
 def dirty_block_indices(
@@ -55,46 +69,47 @@ def dirty_block_indices(
 ) -> Optional[List[int]]:
     """Block indices (of ``block_bytes`` granularity over the raw leaf
     bytes) where ``new`` differs from ``old``; ``None`` means "unknown,
-    write conservatively" (shape/dtype changed, or no detector applies).
+    write conservatively" (shape/dtype changed).
 
-    ``impl="auto"`` picks the exact numpy byte-compare; pass a
-    ``block_delta`` kernel impl (``"pallas"`` / ``"xla"`` /
-    ``"pallas_interpret"``) to flag dirty blocks on-device via
-    ``compute_block_delta``/``pack_dirty``. The kernel output is used
-    ONLY as a dirty detector — the int8-quantized delta it also emits is
-    lossy, so the blocks themselves are always written as exact new
-    bytes by ``TensorStore.save``."""
+    ``impl="auto"`` is the exact numpy byte-compare. A ``block_delta``
+    kernel impl (``"pallas"`` / ``"xla"`` / ``"pallas_interpret"``) flags
+    the blocks of float32 leaves of at least one block whose delta has
+    ``norm2 > 0``, on the device; a kernel that fails raises. Only
+    ``norm2`` comes back to the host — the int8-quantized delta the
+    kernel also emits is lossy, so the blocks themselves are always
+    written as exact new bytes by ``TensorStore.save``. The kernel misses
+    a change whose squared difference underflows to zero or is NaN; the
+    numpy compare misses nothing."""
     new = np.asarray(new)
     old = np.asarray(old)
     if new.dtype != old.dtype or new.shape != old.shape:
         return None
-    nbytes = new.dtype.itemsize * int(new.size)
-    if nbytes == 0:
-        return []
     if impl != "auto" and new.dtype == np.float32 \
             and block_bytes % 4 == 0 and new.size >= block_bytes // 4:
-        try:
-            from repro.kernels.block_delta.ops import (
-                blockify, compute_block_delta, pack_dirty,
-            )
-            block_elems = block_bytes // 4
-            nb = blockify(np.ascontiguousarray(new).reshape(-1), block_elems)
-            ob = blockify(np.ascontiguousarray(old).reshape(-1), block_elems)
-            q, norm2, scale = compute_block_delta(nb, ob, impl=impl)
-            idx, _, _ = pack_dirty(q, norm2, scale)
-            return [int(i) for i in np.asarray(idx)]
-        except Exception:
-            pass  # no accelerator runtime: exact fallback below
-    a = np.frombuffer(np.ascontiguousarray(new).tobytes(), dtype=np.uint8)
-    b = np.frombuffer(np.ascontiguousarray(old).tobytes(), dtype=np.uint8)
-    pad = (-len(a)) % block_bytes
-    if pad:
-        a = np.concatenate([a, np.zeros(pad, np.uint8)])
-        b = np.concatenate([b, np.zeros(pad, np.uint8)])
-    diff = np.any(
-        a.reshape(-1, block_bytes) != b.reshape(-1, block_bytes), axis=1
-    )
-    return [int(i) for i in np.nonzero(diff)[0]]
+        from repro.kernels.block_delta.ops import blockify, compute_block_delta
+
+        block_elems = block_bytes // 4
+        _, norm2, _ = compute_block_delta(
+            blockify(new.reshape(-1), block_elems),
+            blockify(old.reshape(-1), block_elems),
+            impl=impl,
+        )
+        return [int(i) for i in np.flatnonzero(np.asarray(norm2) > 0)]
+    return changed_blocks(new, old, block_bytes)
+
+
+def _frozen(a: np.ndarray) -> bool:
+    """Whether nothing can write ``a``'s memory: it and every array it
+    views are read-only, and no writable Python buffer owns the memory
+    (JAX host arrays, sealed arena views). Such a leaf is kept as the
+    next save's baseline without a copy."""
+    while isinstance(a, np.ndarray):
+        if a.flags.writeable:
+            return False
+        a = a.base
+    if isinstance(a, memoryview):
+        return a.readonly
+    return not isinstance(a, bytearray)
 
 
 class CheckpointManager:
@@ -105,7 +120,11 @@ class CheckpointManager:
     cached runtime is built over it). ``dirty_impl`` selects the dirty
     detector (``"auto"`` = exact numpy; ``"xla"``/``"pallas"`` = the
     block_delta kernel). ``max_staleness_s`` lets ``latest_step`` /
-    ``restore`` be served from the lease tier's bounded-staleness view."""
+    ``restore`` be served from the lease tier's bounded-staleness view.
+
+    A delta save of step N writes ``step_N`` chained to the previous
+    step (``TensorStore`` ``base``): only its dirty blocks are stored,
+    and a restore reads the rest from the chain."""
 
     def __init__(
         self,
@@ -125,40 +144,33 @@ class CheckpointManager:
         self.root = root.rstrip("/")
         self.block_bytes = block_bytes
         self.dirty_impl = dirty_impl
-        self._baseline: Dict[int, Dict[str, np.ndarray]] = {}
+        self._baseline: Dict[str, np.ndarray] = {}
         self._last_step: Optional[int] = None
+        self._chain = 0
 
     # ------------------------------------------------------------------ #
     def save(self, step: int, state: PyTree, *, delta_from_last: bool = True) -> SaveInfo:
         t0 = time.perf_counter()
-        baseline = None
-        if delta_from_last and self._last_step is not None:
-            baseline = self._baseline.get(self._last_step)
         leaves = flatten_with_names(state)
         dirty: Optional[Dict[str, List[int]]] = None
-        if baseline is not None:
+        base = None
+        if delta_from_last and self._last_step is not None \
+                and self._last_step != step and self._chain < DELTA_CHAIN_MAX:
+            base = f"step_{self._last_step}"
             # dirty detection happens ONCE, outside the transaction:
             # a Conflict restart re-runs only the block writes
             dirty = {}
             for lname, arr in leaves:
-                base = baseline.get(lname)
-                if base is None:
+                old = self._baseline.get(lname)
+                if old is None:
                     continue
                 idx = dirty_block_indices(
-                    arr, base, self.block_bytes, self.dirty_impl
+                    arr, old, self.block_bytes, self.dirty_impl
                 )
                 if idx is not None:
                     dirty[lname] = idx
-        stats: Dict[str, int] = {}
 
-        def do_save(fs: FaaSFS) -> None:
-            stats.clear()
-            store = TensorStore(fs, prefix=self.root)
-            s = store.save(
-                f"step_{step}", state, baseline=baseline,
-                block_bytes=self.block_bytes, dirty_blocks=dirty,
-            )
-            stats.update(s)
+        def flip_latest(fs: FaaSFS) -> None:
             # atomically flip the latest pointer (POSIX rename semantics)
             tmp = f"{self.root}/.latest.tmp"
             fd = fs.open(tmp, O_CREAT | O_TRUNC)
@@ -169,17 +181,24 @@ class CheckpointManager:
             fs.rename(tmp, f"{self.root}/latest")
 
         inv = InvocationStats()
-        self.runtime.invoke(do_save, stats=inv)
-        flat = {n: np.asarray(a).copy() for n, a in leaves}
-        self._baseline = {step: flat}
+        stats = save_tree(
+            self.runtime, self.root, f"step_{step}", state,
+            finish=flip_latest, stats=inv, block_bytes=self.block_bytes,
+            dirty_blocks=dirty, base=base,
+        )
+        self._baseline = {}  # release the old one before taking the new
+        self._baseline = {n: a if _frozen(a) else a.copy() for n, a in leaves}
         self._last_step = step
+        self._chain = 0 if base is None else self._chain + 1
         return SaveInfo(
             step=step,
             commit_ts=inv.commit_ts,
-            bytes_total=stats.get("bytes_total", 0),
-            bytes_written=stats.get("bytes_written", 0),
-            blocks_written=stats.get("blocks_written", 0),
+            bytes_total=stats["bytes_total"],
+            bytes_written=stats["bytes_written"],
+            blocks_written=stats["blocks_written"],
             wall_s=time.perf_counter() - t0,
+            txns=stats["txns"],
+            dirty=dirty,
         )
 
     # ------------------------------------------------------------------ #

@@ -182,3 +182,70 @@ def test_arena_buffers_recycle_when_views_die(backend_factory):
     out.clear()
     gc.collect()
     assert arena.outstanding == 0
+
+
+def test_save_tree_splits_a_large_save_and_shows_it_at_once(local):
+    """Past ``part_bytes`` a save takes several transactions; its
+    ``.meta`` rides the last, so ``load`` sees nothing until it is whole."""
+    from repro.core.tensorstate import save_tree
+
+    rt = runtime_for(local)
+
+    class Watch:
+        visible = []
+
+        def invoke(self, fn, *args, **kwargs):
+            out = rt.invoke(fn, *args, **kwargs)
+            self.visible.append(rt.invoke(
+                lambda fs: TensorStore(fs).exists("big"), read_only=True))
+            return out
+
+    t = {"w": np.arange(4096, dtype=np.float32), "b": np.ones(3, np.float32)}
+    stats = save_tree(Watch(), "/mnt/tsfs/state", "big", t,
+                      block_bytes=1024, part_bytes=4096)
+    assert stats["txns"] == 5 and stats["bytes_written"] == 16396
+    assert Watch.visible == [False] * 4 + [True]
+    out = {}
+
+    def load(fs):
+        out["flat"] = TensorStore(fs).load("big", zero_copy=True)
+
+    rt.invoke(load, read_only=True)
+    np.testing.assert_array_equal(out["flat"]["w"], t["w"])
+    np.testing.assert_array_equal(out["flat"]["b"], t["b"])
+
+
+def test_state_larger_than_a_frame_saves_and_loads_over_a_socket(
+        tmp_path, monkeypatch):
+    """Commits and block fetches each stay inside the wire's frame cap:
+    with the cap shrunk to 64 KiB, a 256 KiB leaf still round-trips."""
+    from repro.core import wire
+    from repro.core.backend import BackendService
+    from repro.core.remote import RemoteBackend
+    from repro.core.server import BackendServer
+    from repro.core.tensorstate import save_tree
+
+    monkeypatch.setattr(wire, "MAX_BODY", 64 * 1024)
+    server = BackendServer(BackendService(block_size=4096),
+                           wal_path=str(tmp_path / "wal")).start()
+    rb = RemoteBackend("127.0.0.1", server.port)
+    try:
+        local = LocalServer(rb, max_blocks=8)
+        t = {"w": np.random.default_rng(1).normal(size=65536).astype(np.float32)}
+        stats = save_tree(runtime_for(local), "/mnt/tsfs/state", "m", t,
+                          block_bytes=4096, part_bytes=16 * 1024)
+        assert stats["txns"] == 16
+        rpcs = rb.connection_stats()["rpcs"]
+        for zero_copy in (True, False):
+            out = {}
+
+            def load(fs):
+                out["flat"] = TensorStore(fs).load("m", zero_copy=zero_copy)
+
+            runtime_for(LocalServer(rb, max_blocks=8)).invoke(load, read_only=True)
+            np.testing.assert_array_equal(out["flat"]["w"], t["w"])
+        # 64 blocks per load, at most 4 per fetch frame
+        assert rb.connection_stats()["rpcs"] - rpcs >= 2 * 16
+    finally:
+        rb.close()
+        server.shutdown()

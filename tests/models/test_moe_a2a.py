@@ -16,17 +16,9 @@ SCRIPT = textwrap.dedent("""
     from jax.sharding import PartitionSpec as P
     from repro.models.moe import moe_ffn, moe_ffn_a2a
 
-    # mesh construction + activation across JAX generations: new JAX has
-    # make_mesh(axis_types=...) and jax.set_mesh; old JAX builds a Mesh
-    # directly and uses it as a context manager.
-    if hasattr(jax.sharding, "AxisType"):
-        mesh = jax.make_mesh((2, 2), ("data", "model"),
-                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
-        use_mesh = lambda: jax.set_mesh(mesh)
-    else:
-        mesh = jax.sharding.Mesh(
-            np.array(jax.devices()).reshape(2, 2), ("data", "model"))
-        use_mesh = lambda: mesh
+    mesh = jax.make_mesh((2, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    use_mesh = lambda: jax.set_mesh(mesh)
     key = jax.random.PRNGKey(0)
     B, S, D, E, F, k = 4, 8, 16, 4, 32, 2
     ks = jax.random.split(key, 5)

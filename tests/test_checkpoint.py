@@ -146,3 +146,76 @@ def test_sigkill_during_save_never_restores_torn_checkpoint(tmp_path):
     finally:
         proc2.kill()
         proc2.wait()
+
+
+def test_delta_checkpoint_restores_blocks_it_did_not_write(local):
+    """A delta step stores only its dirty blocks; every restore, latest or
+    historical, reads the rest from the steps it is chained to."""
+    cm = CheckpointManager(local, block_bytes=4096)
+    s1 = {"w": np.arange(4096, dtype=np.float32)}          # 4 blocks
+    cm.save(1, s1)
+    s2 = {"w": s1["w"].copy()}
+    s2["w"][0] = -1.0
+    assert cm.save(2, s2).blocks_written == 1
+    s3 = {"w": s2["w"].copy()}
+    s3["w"][3000] = -2.0
+    assert cm.save(3, s3).blocks_written == 1
+    for step, want in ((3, s3), (2, s2), (1, s1)):
+        for zero_copy in (True, False):
+            got, _ = cm.restore({"w": None}, step=step, zero_copy=zero_copy)
+            np.testing.assert_array_equal(got["w"], want["w"])
+
+
+def test_delta_chain_is_cut_by_a_full_save():
+    from repro.state.checkpoint import DELTA_CHAIN_MAX
+
+    cm = CheckpointManager(LocalServer(BackendService(block_size=512)),
+                           block_bytes=4096)
+    s = {"w": np.zeros(4096, np.float32)}
+    infos = []
+    for step in range(DELTA_CHAIN_MAX + 2):
+        s = {"w": s["w"].copy()}
+        s["w"][step] += 1.0
+        infos.append(cm.save(step, s))
+    full = [i.step for i in infos if i.bytes_written == i.bytes_total]
+    assert full == [0, DELTA_CHAIN_MAX + 1]
+    got, _ = cm.restore({"w": None})
+    np.testing.assert_array_equal(got["w"], s["w"])
+
+
+def test_kernel_detector_writes_what_the_numpy_compare_writes():
+    rng = np.random.default_rng(3)
+    base = {
+        "w": rng.normal(size=(8, 1024)).astype(np.float32),   # 8 blocks
+        "small": np.ones(10, np.float32),                     # < 1 block
+        "n": np.int32(3),
+    }
+    new = {k: np.array(v) for k, v in base.items()}
+    new["w"][2, 5] += 1.0
+    new["w"][7, 1000] += 1.0
+    new["small"][0] = 2.0
+    infos = {}
+    for impl in ("auto", "pallas_interpret"):
+        cm = CheckpointManager(LocalServer(BackendService(block_size=512)),
+                               block_bytes=4096, dirty_impl=impl)
+        cm.save(0, base)
+        infos[impl] = cm.save(1, new)
+    auto, kern = infos["auto"], infos["pallas_interpret"]
+    assert auto.dirty["w"] == [2, 7]
+    assert (kern.dirty, kern.blocks_written, kern.bytes_written) == \
+        (auto.dirty, auto.blocks_written, auto.bytes_written)
+
+
+def test_a_failing_kernel_detector_raises(monkeypatch):
+    from repro.kernels.block_delta import ops
+
+    def refused(*args, **kwargs):
+        raise RuntimeError("kernel refused by the compiler")
+
+    monkeypatch.setattr(ops, "compute_block_delta", refused)
+    cm = CheckpointManager(LocalServer(BackendService(block_size=512)),
+                           block_bytes=4096, dirty_impl="pallas")
+    s = {"w": np.zeros((8, 1024), np.float32)}
+    cm.save(0, s)
+    with pytest.raises(RuntimeError, match="refused"):
+        cm.save(1, {"w": s["w"] + 1.0})

@@ -3,12 +3,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.launch.hlo_analysis import (
-    HloCostModel,
-    analyze_text,
-    parse_type,
-    xla_cost,
-)
+from repro.launch.hlo_analysis import HloCostModel, analyze_text, parse_type
 
 
 def _compile(fn, *args):
@@ -45,8 +40,8 @@ def test_while_trip_count_correction():
     cu = _compile(unrolled, w, x)
     mine_s = analyze_text(cs.as_text())["flops_per_device"]
     mine_u = analyze_text(cu.as_text())["flops_per_device"]
-    xla_u = xla_cost(cu)["flops"]
-    xla_s = xla_cost(cs)["flops"]
+    xla_u = cu.cost_analysis()["flops"]
+    xla_s = cs.cost_analysis()["flops"]
     # XLA undercounts the scan: body visited once
     assert xla_s < xla_u / 2
     # our corrected count matches the unrolled one within 10%
