@@ -32,7 +32,9 @@ import numpy as np
 
 from repro.core.backend import BackendService
 from repro.core.client import LocalServer
+from repro.core.posix import O_CREAT
 from repro.core.remote import RemoteBackend
+from repro.core.runtime import runtime_for
 from repro.core.server import BackendServer
 from repro.state.checkpoint import CheckpointManager
 
@@ -99,13 +101,14 @@ def run() -> List[str]:
 
         # -- WAL checkpoint cycles: full, then delta ------------------- #
         s_full = server.run_checkpoint(full=True)
-        # dirty one block of one root, then cycle again: the delta must
-        # serialize only the chains past the base's version floor
-        txn = LocalServer(rb).begin()
-        fd_path = "/mnt/tsfs/ckpt0.01/step_1/w"
-        fid = txn.lookup(fd_path)
-        txn.write(fid, 0, b"\x42" * block_bytes)
-        txn.commit()
+        # write one block, then cycle again: the delta must serialize
+        # only the chains past the base's version floor
+        def touch(fs):
+            fd = fs.open("/mnt/tsfs/ckpt0.01/touched", O_CREAT)
+            fs.pwrite(fd, b"\x42" * block_bytes, 0)
+            fs.close(fd)
+
+        runtime_for(LocalServer(rb)).invoke(touch)
         s_delta = server.run_checkpoint()
         assert s_delta["base_seg"] == s_full["seg"], "delta did not chain"
         rows.append(
@@ -139,7 +142,7 @@ def run() -> List[str]:
             new = _dirty(base, frac, rng)
             nb = blockify(new, BLOCK_ELEMS)
             ob = blockify(base, BLOCK_ELEMS)
-            q, norm2, scale = compute_block_delta(
+            q, norm2, scale, _ = compute_block_delta(
                 jnp.asarray(nb), jnp.asarray(ob), impl="xla"
             )
             dirty_idx, qd, sd = pack_dirty(

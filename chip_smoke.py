@@ -11,7 +11,8 @@ Runs qwen2-1.5b at its published widths (f32 params from
      the server is plain Python, so this process alone holds the chip;
      every client reaches it over ``RemoteBackend`` -> ``LocalServer``
      -> ``FunctionRuntime``;
-  1. publish   ``TensorStore`` save of the params (the trainer's commit);
+  1. publish   the params saved as a new version of the served state
+               (``save_tree``, the trainer's commit);
   2. serve     a ``SnapshotServer`` replica refreshes and serves 3 batches
                of greedy decode; tokens must equal a decode of the
                in-memory params;
@@ -19,9 +20,9 @@ Runs qwen2-1.5b at its published widths (f32 params from
                seeded ~1% of 256 KiB blocks perturbed, delta save; the
                kernel's dirty set must equal the exact numpy set in every
                leaf and the blocks written the perturbed count;
-  4. refresh   the trainer commits the perturbed params, the replica
-               refreshes and serves again; tokens must equal a decode of
-               the perturbed in-memory params;
+  4. refresh   the trainer commits the perturbed params as a delta
+               version, the replica refreshes and serves again; tokens
+               must equal a decode of the perturbed in-memory params;
   5. restore   zero-copy ``CheckpointManager.restore``, digest-equal to
                the saved state.
 
@@ -266,8 +267,9 @@ def run_phases(cfg, port: int, *, impl: str = "pallas", seed: int = 0,
         del params_dev
         with phase("refresh") as info:
             st = serve.publish(trainer, new_params, baseline=old_flat)
-            check(st["blocks_written"] == n_dirty,
-                  f"trainer commit wrote {st['blocks_written']} blocks")
+            check(st["blocks_written"] == n_dirty and st["base"] is not None,
+                  f"trainer commit wrote {st['blocks_written']} blocks, "
+                  f"chained to {st['base']}")
             v2 = replica.refresh()
             check(v2 > v1, f"refresh stayed at version {v1}")
             got = [replica.serve(p) for p in prompts]

@@ -15,10 +15,15 @@ block-granular machinery gives us, for free:
     paper's TPC-C warehouses: disjoint-block commits interleave without
     locks; conflicting commits abort and retry.
 
-A save is either in place (same name, only changed blocks rewritten) or
-chained: a save under a new name with ``base=<earlier name>`` writes only
-its changed blocks, lists them per leaf in its ``.meta``, and ``load``
-reads every other block from the base chain.
+A save is in place or versioned. ``TensorStore.save`` writes into
+``name`` inside the caller's transaction, so concurrent writers of
+disjoint blocks commit without conflict. ``save_tree`` writes a new
+version ``name@<token>`` in as many transactions as its size needs and,
+in the last one, points ``name``'s ``.meta`` at it: readers load the old
+version or the new one whole, and a version is never rewritten. A delta
+version lists its written blocks per leaf and names the version it was
+compared with as its ``base``; ``load`` reads every other block from
+that chain.
 
 The on-device companion is the ``block_delta`` Pallas kernel, which computes
 per-block dirty masks / int8-quantized deltas so only changed blocks cross
@@ -27,6 +32,7 @@ the wire (gradient/update compression keyed to block layout).
 from __future__ import annotations
 
 import json
+import secrets
 import weakref
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
@@ -36,14 +42,17 @@ import numpy as np
 from repro.core.arena import BlockArena, default_arena
 from repro.core.posix import FaaSFS, O_CREAT, O_TRUNC
 from repro.core.types import TENSOR_BLOCK_BYTES, NotFound
-from repro.core.wire import MAX_BODY
 
 PyTree = Any
 
-#: write-set bytes one save transaction carries. A commit rides one wire
-#: frame, whose body is capped at ``MAX_BODY``, so ``save_tree`` splits a
-#: larger save into several transactions.
-TXN_WRITE_BYTES = MAX_BODY // 4
+#: write-set bytes one ``save_tree`` transaction carries. A commit is one
+#: wire frame and one WAL record, and the client holds its write set until
+#: it commits, so a larger save is split (read each time ``save_tree`` runs)
+TXN_WRITE_BYTES = 64 << 20
+
+#: delta versions chained after a full one before ``save_tree`` writes the
+#: next in full, so a load walks a bounded chain
+DELTA_CHAIN_MAX = 8
 
 
 def flatten_with_names(tree: PyTree, prefix: str = "") -> List[Tuple[str, np.ndarray]]:
@@ -104,8 +113,7 @@ class SavePlan:
     and the ``.meta`` that makes the save visible to ``load``."""
 
     meta: Dict[str, Any]
-    #: (leaf name, leaf bytes, [(start, stop) byte spans]); an empty span
-    #: list still creates/sizes the file
+    #: (leaf name, leaf bytes, [(start, stop) byte spans])
     leaves: List[Tuple[str, np.ndarray, List[Tuple[int, int]]]] = \
         field(default_factory=list)
     stats: Dict[str, int] = field(default_factory=dict)
@@ -118,8 +126,6 @@ class SavePlan:
         parts: List[List[Tuple[str, np.ndarray, int, int]]] = [[]]
         used = 0
         for lname, raw, spans in self.leaves:
-            if not spans:
-                parts[-1].append((lname, raw, 0, 0))
             for lo, hi in spans:
                 while lo < hi:
                     if used >= step:
@@ -206,40 +212,39 @@ class TensorStore:
         baseline: Optional[Dict[str, np.ndarray]] = None,
         block_bytes: int = TENSOR_BLOCK_BYTES,
         dirty_blocks: Optional[Dict[str, Iterable[int]]] = None,
-        base: Optional[str] = None,
     ) -> Dict[str, int]:
-        """Write a pytree in this transaction. With ``baseline`` (previous
-        leaf arrays), only blocks whose bytes changed are written — the
-        delta-commit path.
+        """Write a pytree into ``name`` in place, in this transaction. With
+        ``baseline`` (previous leaf arrays), only blocks whose bytes
+        changed are written — the delta-commit path.
 
         ``dirty_blocks`` short-circuits the byte-compare: for a leaf
         listed there, ONLY the given block indices are written (exact
         new bytes — the mask is a detector, never a value source), so a
         kernel-computed dirty mask (``compute_block_delta``) drives the
         write set without touching the clean bytes at all. Leaves absent
-        from the dict fall back to baseline comparison.
-
-        Without ``base`` the unwritten blocks are whatever ``name`` held
-        before (in place). With ``base`` (an earlier save's name) the
-        save is chained: ``load`` reads every block this save did not
-        write from ``base``.
+        from the dict fall back to baseline comparison. Both apply only
+        where ``name`` already holds an in-place save, whose blocks the
+        unwritten ones keep; otherwise every block is written.
 
         Returns stats: leaves, bytes_total, bytes_written, blocks_written.
         """
+        if (baseline is not None or dirty_blocks) and not (
+                self.exists(name) and "ref" not in self.read_meta(name)):
+            baseline = dirty_blocks = None
         plan = plan_save(tree, baseline=baseline, block_bytes=block_bytes,
-                         dirty_blocks=dirty_blocks, base=base)
-        for part in plan.parts(max(plan.stats["bytes_written"], 1)):
-            self.write_part(name, part)
+                         dirty_blocks=dirty_blocks)
+        self.write_part(name, [(lname, raw, lo, hi)
+                               for lname, raw, spans in plan.leaves
+                               for lo, hi in spans])
         self.write_meta(name, plan.meta)
         return plan.stats
 
     def write_part(self, name: str,
                    part: List[Tuple[str, np.ndarray, int, int]]) -> None:
-        """Write one part of a ``SavePlan`` (leaf byte spans)."""
+        """Write ``(leaf, leaf bytes, start, stop)`` spans of a ``SavePlan``."""
         for lname, raw, lo, hi in part:
             fd = self.fs.open(self._leaf_path(name, lname), O_CREAT)
-            if hi > lo:
-                self.fs.pwrite(fd, memoryview(raw)[lo:hi], lo)
+            self.fs.pwrite(fd, memoryview(raw)[lo:hi], lo)
             self.fs.ftruncate(fd, len(raw))
             self.fs.close(fd)
 
@@ -255,6 +260,13 @@ class TensorStore:
         meta = json.loads(self.fs.pread(mfd, size, 0))
         self.fs.close(mfd)
         return meta
+
+    def resolve(self, name: str) -> Tuple[str, Dict[str, Any]]:
+        """``(save, meta)`` that ``name`` holds: ``name`` itself after an
+        in-place save, else the version its ``.meta`` points at."""
+        meta = self.read_meta(name)
+        ref = meta.get("ref")
+        return (name, meta) if ref is None else (ref, self.read_meta(ref))
 
     def _sources(self, name: str, leaf: Dict[str, Any], nbytes: int,
                  metas: Dict[str, Dict[str, Any]]) -> List[Tuple[str, List[Tuple[int, int]]]]:
@@ -298,8 +310,8 @@ class TensorStore:
         arena buffers; they stay valid as long as any view is alive and
         the backing buffer is recycled when the last one dies. Callers
         that need to mutate must ``.copy()``."""
-        metas = {name: self.read_meta(name)}
-        meta = metas[name]
+        name, meta = self.resolve(name)
+        metas = {name: meta}
         # tensor-sized readahead: the meta layout names every leaf, so
         # one lookup_many primes the whole checkpoint's name->fid map
         # before any data moves (readdir-free, single round trip)
@@ -363,33 +375,63 @@ def save_tree(
     *,
     finish: Optional[Callable[[FaaSFS], None]] = None,
     stats=None,
-    part_bytes: int = TXN_WRITE_BYTES,
+    base: Optional[str] = None,
     **plan_kw: Any,
-) -> Dict[str, int]:
-    """``TensorStore.save`` as invocations on ``runtime`` (a
-    ``FunctionRuntime``), in as many transactions as the write set needs
-    at ``part_bytes`` each. The ``.meta`` — and ``finish(fs)``, e.g. a
-    pointer flip — ride the last one, so a save that fits one part is one
-    atomic transaction, and a larger one becomes visible to ``load`` at
-    once when its last part commits. A split save should target a name
-    no reader is loading: its earlier parts overwrite blocks in place.
+) -> Dict[str, Any]:
+    """Save ``tree`` as a new version of ``name`` through ``runtime`` (a
+    ``FunctionRuntime``). The version, ``name@<token>``, is written in as
+    many transactions as ``TXN_WRITE_BYTES`` needs; the last one writes
+    its ``.meta``, points ``name`` at it and runs ``finish(fs)`` (e.g. a
+    pointer flip). Readers of ``name`` load the old version or the new
+    one whole, and saves chained to the old one keep reading it.
+
+    ``baseline`` / ``dirty_blocks`` (see ``TensorStore.save``) make the
+    version a delta of the version ``base`` (default: ``name``) holds:
+    only its changed blocks are written, and ``load`` reads the others
+    from that version's chain. It is written in full instead where
+    ``base`` holds no version, or where the chain would grow past
+    ``DELTA_CHAIN_MAX`` deltas.
+
     ``stats`` (an ``InvocationStats``) receives the last transaction's.
-    Returns the plan's stats plus ``txns``."""
-    plan = plan_save(tree, **plan_kw)
-    parts = plan.parts(part_bytes)
+    Returns the plan's stats plus ``txns`` and ``base`` (the version the
+    delta is chained to, or None)."""
+    base = name if base is None else base
+    found: Dict[str, Any] = {}
+    if plan_kw.get("baseline") is not None or plan_kw.get("dirty_blocks"):
+        def resolve(fs: FaaSFS) -> None:
+            store = TensorStore(fs, prefix=prefix)
+            if store.exists(base):
+                found["version"], found["meta"] = store.resolve(base)
+
+        # read-write: validated at commit, never a stale lease view
+        runtime.invoke(resolve, read_only=False)
+    version = found.get("version")
+    meta = found.get("meta", {})
+    depth = meta.get("depth", 0) + 1
+    if version in (None, base) or depth > DELTA_CHAIN_MAX or \
+            meta.get("block_bytes") != plan_kw.get("block_bytes",
+                                                   TENSOR_BLOCK_BYTES):
+        plan_kw.pop("baseline", None)
+        plan_kw.pop("dirty_blocks", None)
+        version, depth = None, 0
+    plan = plan_save(tree, base=version, **plan_kw)
+    plan.meta["depth"] = depth
+    parts = plan.parts(TXN_WRITE_BYTES)
+    target = f"{name}@{secrets.token_hex(8)}"
 
     def write(fs: FaaSFS, part, last: bool) -> None:
         store = TensorStore(fs, prefix=prefix)
-        store.write_part(name, part)
+        store.write_part(target, part)
         if last:
-            store.write_meta(name, plan.meta)
+            store.write_meta(target, plan.meta)
+            store.write_meta(name, {"ref": target})
             if finish is not None:
                 finish(fs)
 
     for i, part in enumerate(parts):
         last = i == len(parts) - 1
         runtime.invoke(write, part, last, stats=stats if last else None)
-    return dict(plan.stats, txns=len(parts))
+    return dict(plan.stats, txns=len(parts), base=version)
 
 
 def unflatten_like(template: PyTree, flat: Dict[str, np.ndarray]) -> PyTree:

@@ -14,7 +14,8 @@ from repro.kernels.block_delta.ref import apply_delta_ref, block_delta_ref
 
 @functools.partial(jax.jit, static_argnames=("impl",))
 def compute_block_delta(new: jax.Array, old: jax.Array, *, impl: str = "pallas"):
-    """new/old: (nblocks, block_elems) -> (q int8, norm2 f32, scale f32)."""
+    """new/old: (nblocks, block_elems) -> (q int8, norm2 f32, scale f32,
+    nchanged int32: elements per block whose bits differ)."""
     if impl == "xla":
         return block_delta_ref(new, old)
     return block_delta(new, old, interpret=(impl == "pallas_interpret"))

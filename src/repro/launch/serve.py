@@ -31,10 +31,10 @@ ROOT, NAME = "/mnt/tsfs/train", "state"
 
 def publish(local: LocalServer, params: Any, *,
             baseline: Optional[Dict[str, np.ndarray]] = None) -> Dict[str, int]:
-    """Commit ``{"params": params}`` as the served state: in place, in as
-    many transactions as its size needs (``save_tree``). ``baseline``
-    (the flat leaves last published) writes only the changed blocks,
-    compared at the backend's block size."""
+    """Commit ``{"params": params}`` as a new version of the served state
+    (``save_tree``): a replica refreshing meanwhile loads the old version
+    or the new one whole. ``baseline`` (the flat leaves last published)
+    writes only the changed blocks, compared at the backend's block size."""
     return save_tree(runtime_for(local), ROOT, NAME, {"params": params},
                      baseline=baseline, block_bytes=local.backend.block_size)
 

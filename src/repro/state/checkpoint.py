@@ -2,17 +2,18 @@
 
 Checkpoints are FaaSFS state objects:
 
-  * ``save`` runs as ONE transaction — a checkpoint is atomically visible or
-    not at all (no torn checkpoints on worker failure; the paper's atomic
-    commit applied to training state). A save whose write set exceeds
-    ``TXN_WRITE_BYTES`` commits its blocks in several transactions into
-    its own ``step_N`` directory and flips ``latest`` in the last one, so
-    it is still seen whole or not at all,
+  * a checkpoint is atomically visible or not at all (no torn
+    checkpoints on worker failure; the paper's atomic commit applied to
+    training state): ``save_tree`` writes step N as a new version of
+    ``step_N``, in several transactions when its write set exceeds
+    ``TXN_WRITE_BYTES``, and the last one points ``step_N`` at it and
+    flips ``latest``. Saving a step again writes another version, and
+    steps chained to the old one keep reading it,
   * consecutive saves ship only dirty blocks: the ``block_delta`` kernel
     (or the exact numpy compare) flags dirty blocks against the previous
-    step's baseline, and ``TensorStore.save`` writes ONLY those blocks'
-    exact new bytes, chained to the previous step — checkpoint cost
-    scales with the update rate, not the parameter count,
+    step's baseline, and ONLY those blocks' exact new bytes are written,
+    chained to the previous step's version — checkpoint cost scales with
+    the update rate, not the parameter count,
   * ``restore`` pins a snapshot timestamp (multiversion read) so a restore
     is consistent even while training keeps committing, and loads through
     the zero-copy arena path (``TensorStore.load(zero_copy=True)``),
@@ -42,11 +43,6 @@ from repro.core.tensorstate import (
 PyTree = Any
 
 
-#: consecutive delta saves before the next save is written in full, so a
-#: restore walks a bounded chain of bases
-DELTA_CHAIN_MAX = 8
-
-
 @dataclass
 class SaveInfo:
     step: int
@@ -58,6 +54,8 @@ class SaveInfo:
     #: transactions the save took (more than one past ``TXN_WRITE_BYTES``)
     txns: int = 1
     #: per-leaf dirty block indices a delta save wrote; ``None`` when full
+    #: (first save, the same step again, or a chain cut by
+    #: ``DELTA_CHAIN_MAX``)
     dirty: Optional[Dict[str, List[int]]] = None
 
 
@@ -73,13 +71,12 @@ def dirty_block_indices(
 
     ``impl="auto"`` is the exact numpy byte-compare. A ``block_delta``
     kernel impl (``"pallas"`` / ``"xla"`` / ``"pallas_interpret"``) flags
-    the blocks of float32 leaves of at least one block whose delta has
-    ``norm2 > 0``, on the device; a kernel that fails raises. Only
-    ``norm2`` comes back to the host — the int8-quantized delta the
-    kernel also emits is lossy, so the blocks themselves are always
-    written as exact new bytes by ``TensorStore.save``. The kernel misses
-    a change whose squared difference underflows to zero or is NaN; the
-    numpy compare misses nothing."""
+    the blocks of float32 leaves of at least one block on the device,
+    where any element's bits differ — as exact as the byte compare, NaNs,
+    signed zeros and subnormal steps included; a kernel that fails
+    raises. Only that per-block count comes back to the host — the
+    int8-quantized delta the kernel also emits is lossy, so the blocks
+    themselves are always written as exact new bytes."""
     new = np.asarray(new)
     old = np.asarray(old)
     if new.dtype != old.dtype or new.shape != old.shape:
@@ -89,12 +86,12 @@ def dirty_block_indices(
         from repro.kernels.block_delta.ops import blockify, compute_block_delta
 
         block_elems = block_bytes // 4
-        _, norm2, _ = compute_block_delta(
+        *_, nchanged = compute_block_delta(
             blockify(new.reshape(-1), block_elems),
             blockify(old.reshape(-1), block_elems),
             impl=impl,
         )
-        return [int(i) for i in np.flatnonzero(np.asarray(norm2) > 0)]
+        return [int(i) for i in np.flatnonzero(np.asarray(nchanged))]
     return changed_blocks(new, old, block_bytes)
 
 
@@ -122,9 +119,9 @@ class CheckpointManager:
     block_delta kernel). ``max_staleness_s`` lets ``latest_step`` /
     ``restore`` be served from the lease tier's bounded-staleness view.
 
-    A delta save of step N writes ``step_N`` chained to the previous
-    step (``TensorStore`` ``base``): only its dirty blocks are stored,
-    and a restore reads the rest from the chain."""
+    A delta save of step N writes a version of ``step_N`` chained to the
+    previous step's version (``save_tree`` ``base``): only its dirty
+    blocks are stored, and a restore reads the rest from the chain."""
 
     def __init__(
         self,
@@ -146,7 +143,6 @@ class CheckpointManager:
         self.dirty_impl = dirty_impl
         self._baseline: Dict[str, np.ndarray] = {}
         self._last_step: Optional[int] = None
-        self._chain = 0
 
     # ------------------------------------------------------------------ #
     def save(self, step: int, state: PyTree, *, delta_from_last: bool = True) -> SaveInfo:
@@ -155,7 +151,7 @@ class CheckpointManager:
         dirty: Optional[Dict[str, List[int]]] = None
         base = None
         if delta_from_last and self._last_step is not None \
-                and self._last_step != step and self._chain < DELTA_CHAIN_MAX:
+                and self._last_step != step:
             base = f"step_{self._last_step}"
             # dirty detection happens ONCE, outside the transaction:
             # a Conflict restart re-runs only the block writes
@@ -189,7 +185,6 @@ class CheckpointManager:
         self._baseline = {}  # release the old one before taking the new
         self._baseline = {n: a if _frozen(a) else a.copy() for n, a in leaves}
         self._last_step = step
-        self._chain = 0 if base is None else self._chain + 1
         return SaveInfo(
             step=step,
             commit_ts=inv.commit_ts,
@@ -198,7 +193,7 @@ class CheckpointManager:
             blocks_written=stats["blocks_written"],
             wall_s=time.perf_counter() - t0,
             txns=stats["txns"],
-            dirty=dirty,
+            dirty=dirty if stats["base"] else None,
         )
 
     # ------------------------------------------------------------------ #

@@ -184,11 +184,14 @@ def test_arena_buffers_recycle_when_views_die(backend_factory):
     assert arena.outstanding == 0
 
 
-def test_save_tree_splits_a_large_save_and_shows_it_at_once(local):
-    """Past ``part_bytes`` a save takes several transactions; its
-    ``.meta`` rides the last, so ``load`` sees nothing until it is whole."""
+def test_save_tree_splits_a_large_save_and_shows_it_at_once(local, monkeypatch):
+    """Past ``TXN_WRITE_BYTES`` a save takes several transactions; the
+    pointer to its version rides the last, so ``load`` sees nothing until
+    it is whole."""
+    from repro.core import tensorstate
     from repro.core.tensorstate import save_tree
 
+    monkeypatch.setattr(tensorstate, "TXN_WRITE_BYTES", 4096)
     rt = runtime_for(local)
 
     class Watch:
@@ -201,8 +204,7 @@ def test_save_tree_splits_a_large_save_and_shows_it_at_once(local):
             return out
 
     t = {"w": np.arange(4096, dtype=np.float32), "b": np.ones(3, np.float32)}
-    stats = save_tree(Watch(), "/mnt/tsfs/state", "big", t,
-                      block_bytes=1024, part_bytes=4096)
+    stats = save_tree(Watch(), "/mnt/tsfs/state", "big", t, block_bytes=1024)
     assert stats["txns"] == 5 and stats["bytes_written"] == 16396
     assert Watch.visible == [False] * 4 + [True]
     out = {}
@@ -215,17 +217,62 @@ def test_save_tree_splits_a_large_save_and_shows_it_at_once(local):
     np.testing.assert_array_equal(out["flat"]["b"], t["b"])
 
 
+def test_split_publish_is_seen_whole_by_a_refreshing_replica(local, monkeypatch):
+    """A save of a name replicas serve, split over several transactions,
+    writes a new version: a replica refreshing after each part sees the
+    old state whole until the last part commits, then the new one whole
+    — for a full rewrite and for a delta."""
+    from repro.core import tensorstate
+    from repro.core.tensorstate import save_tree
+    from repro.serving.engine import SnapshotServer
+
+    monkeypatch.setattr(tensorstate, "TXN_WRITE_BYTES", 2048)
+    rt = runtime_for(local)
+    replica = SnapshotServer(LocalServer(local.backend), lambda p, b: p,
+                             {"w": None, "b": None})
+    seen = []
+    invoke = rt.invoke
+
+    def watched(fn, *args, **kwargs):
+        out = invoke(fn, *args, **kwargs)
+        replica.refresh()
+        seen.append({k: np.array(v) for k, v in replica.params.items()})
+        return out
+
+    def save(t, **kw):
+        return save_tree(rt, "/mnt/tsfs/train", "state", t,
+                         block_bytes=256, **kw)
+
+    def same(a, b):
+        return all(np.array_equal(a[k], b[k]) for k in a)
+
+    v1 = {"w": np.arange(2048, dtype=np.float32), "b": np.zeros(8, np.float32)}
+    save(v1)
+    monkeypatch.setattr(rt, "invoke", watched)
+    v2 = {"w": -1.0 - v1["w"], "b": np.ones(8, np.float32)}
+    v3 = {"w": v2["w"].copy(), "b": v2["b"]}
+    v3["w"][::128] = 7.0                      # every other 256 B block
+    for old, new, kw in ((v1, v2, {}), (v2, v3, {"baseline": v2})):
+        seen.clear()
+        stats = save(new, **kw)
+        assert stats["txns"] > 1
+        assert [same(s, old) for s in seen[:-1]] == [True] * (len(seen) - 1)
+        assert same(seen[-1], new)
+    assert stats["base"] is not None and stats["blocks_written"] == 16
+
+
 def test_state_larger_than_a_frame_saves_and_loads_over_a_socket(
         tmp_path, monkeypatch):
     """Commits and block fetches each stay inside the wire's frame cap:
     with the cap shrunk to 64 KiB, a 256 KiB leaf still round-trips."""
-    from repro.core import wire
+    from repro.core import tensorstate, wire
     from repro.core.backend import BackendService
     from repro.core.remote import RemoteBackend
     from repro.core.server import BackendServer
     from repro.core.tensorstate import save_tree
 
     monkeypatch.setattr(wire, "MAX_BODY", 64 * 1024)
+    monkeypatch.setattr(tensorstate, "TXN_WRITE_BYTES", 16 * 1024)
     server = BackendServer(BackendService(block_size=4096),
                            wal_path=str(tmp_path / "wal")).start()
     rb = RemoteBackend("127.0.0.1", server.port)
@@ -233,7 +280,7 @@ def test_state_larger_than_a_frame_saves_and_loads_over_a_socket(
         local = LocalServer(rb, max_blocks=8)
         t = {"w": np.random.default_rng(1).normal(size=65536).astype(np.float32)}
         stats = save_tree(runtime_for(local), "/mnt/tsfs/state", "m", t,
-                          block_bytes=4096, part_bytes=16 * 1024)
+                          block_bytes=4096)
         assert stats["txns"] == 16
         rpcs = rb.connection_stats()["rpcs"]
         for zero_copy in (True, False):

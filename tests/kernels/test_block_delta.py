@@ -16,17 +16,19 @@ def test_kernel_vs_ref(nb, be, dtype):
     ks = jax.random.split(jax.random.PRNGKey(0), 2)
     new = jax.random.normal(ks[0], (nb, be), dtype)
     old = new + jax.random.normal(ks[1], (nb, be), dtype) * 0.01
-    qk, nk, sk = compute_block_delta(new, old, impl="pallas_interpret")
-    qr, nr, sr = compute_block_delta(new, old, impl="xla")
+    qk, nk, sk, ck = compute_block_delta(new, old, impl="pallas_interpret")
+    qr, nr, sr, cr = compute_block_delta(new, old, impl="xla")
     assert int(jnp.sum(jnp.abs(qk.astype(jnp.int32) - qr.astype(jnp.int32)))) == 0
     np.testing.assert_allclose(np.asarray(nk), np.asarray(nr), rtol=1e-5)
     np.testing.assert_allclose(np.asarray(sk), np.asarray(sr), rtol=1e-5)
+    np.testing.assert_array_equal(np.asarray(ck), np.asarray(cr))
 
 
 def test_identical_blocks_have_zero_norm():
     x = jnp.ones((4, 128), jnp.float32)
-    q, norm2, scale = compute_block_delta(x, x, impl="pallas_interpret")
+    q, norm2, scale, nchanged = compute_block_delta(x, x, impl="pallas_interpret")
     assert float(jnp.max(norm2)) == 0.0
+    assert int(jnp.max(nchanged)) == 0
     assert int(jnp.max(jnp.abs(q.astype(jnp.int32)))) == 0
 
 
@@ -34,7 +36,7 @@ def test_quantized_roundtrip_error_bounded():
     ks = jax.random.split(jax.random.PRNGKey(1), 2)
     old = jax.random.normal(ks[0], (8, 256), jnp.float32)
     new = old + jax.random.normal(ks[1], (8, 256), jnp.float32) * 0.05
-    q, norm2, scale = compute_block_delta(new, old, impl="pallas_interpret")
+    q, norm2, scale, _ = compute_block_delta(new, old, impl="pallas_interpret")
     rec = apply_delta_ref(old, q, scale)
     # int8 quantization error is bounded by scale/2 per element
     err = np.max(np.abs(np.asarray(rec) - np.asarray(new)))
@@ -46,7 +48,7 @@ def test_pack_dirty_selects_changed_blocks_only():
     new = old.copy()
     new[1] += 0.5
     new[4] += 0.1
-    q, norm2, scale = compute_block_delta(jnp.asarray(new), jnp.asarray(old), impl="xla")
+    q, norm2, scale, _ = compute_block_delta(jnp.asarray(new), jnp.asarray(old), impl="xla")
     idx, qd, sd = pack_dirty(np.asarray(q), np.asarray(norm2), np.asarray(scale))
     assert list(idx) == [1, 4]
     assert qd.shape == (2, 64)

@@ -166,8 +166,26 @@ def test_delta_checkpoint_restores_blocks_it_did_not_write(local):
             np.testing.assert_array_equal(got["w"], want["w"])
 
 
+def test_resaving_a_step_keeps_the_steps_chained_to_it(local):
+    """Saving a step again writes a new version of it: a delta step
+    chained to the old version still restores exactly."""
+    cm = CheckpointManager(local, block_bytes=4096)
+    s1 = {"w": np.arange(4096, dtype=np.float32)}
+    cm.save(1, s1)
+    s2 = {"w": s1["w"].copy()}
+    s2["w"][0] = -1.0
+    assert cm.save(2, s2).dirty == {"w": [0]}
+    again = CheckpointManager(LocalServer(local.backend), block_bytes=4096)
+    s1b = {"w": s1["w"] + 10.0}
+    info = again.save(1, s1b)
+    assert info.dirty is None and info.bytes_written == info.bytes_total
+    for step, want in ((2, s2), (1, s1b)):
+        got, _ = cm.restore({"w": None}, step=step)
+        np.testing.assert_array_equal(got["w"], want["w"])
+
+
 def test_delta_chain_is_cut_by_a_full_save():
-    from repro.state.checkpoint import DELTA_CHAIN_MAX
+    from repro.core.tensorstate import DELTA_CHAIN_MAX
 
     cm = CheckpointManager(LocalServer(BackendService(block_size=512)),
                            block_bytes=4096)
@@ -219,3 +237,27 @@ def test_a_failing_kernel_detector_raises(monkeypatch):
     cm.save(0, s)
     with pytest.raises(RuntimeError, match="refused"):
         cm.save(1, {"w": s["w"] + 1.0})
+
+
+@pytest.mark.parametrize("change", ["nan", "signed_zero", "tiny", "nan_payload"])
+def test_kernel_detector_sees_changes_that_square_to_zero(change):
+    """Dirty means the bits differ: the kernel flags a NaN, a sign flip
+    of zero and a change whose square underflows, exactly where the
+    numpy byte compare does — and an unchanged NaN nowhere."""
+    from repro.state.checkpoint import dirty_block_indices
+
+    old = np.zeros((4, 1024), np.float32)      # 4 blocks of 4 KiB
+    old[3, 7] = np.nan
+    new = old.copy()
+    if change == "nan":
+        new[1, 5] = np.nan
+    elif change == "signed_zero":
+        new[1, 5] = -0.0
+    elif change == "tiny":
+        new[1, 5] = 1e-30                      # 1e-60 flushes to zero
+    else:
+        new.view(np.uint32)[3, 7] ^= 1         # another NaN bit pattern
+    want = [3] if change == "nan_payload" else [1]
+    auto = dirty_block_indices(new, old, 4096, "auto")
+    kern = dirty_block_indices(new, old, 4096, "pallas_interpret")
+    assert auto == kern == want
