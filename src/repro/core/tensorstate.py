@@ -386,15 +386,16 @@ def save_tree(
     one whole, and saves chained to the old one keep reading it.
 
     ``baseline`` / ``dirty_blocks`` (see ``TensorStore.save``) make the
-    version a delta of the version ``base`` (default: ``name``) holds:
-    only its changed blocks are written, and ``load`` reads the others
-    from that version's chain. It is written in full instead where
-    ``base`` holds no version, or where the chain would grow past
-    ``DELTA_CHAIN_MAX`` deltas.
+    version a delta of ``base``, the version they were compared with: a
+    version this function returned, or a name, read as the version it
+    holds now (default: ``name``). Only the changed blocks are written,
+    and ``load`` reads the others from that version's chain. It is
+    written in full instead where ``base`` holds no version, or where the
+    chain would grow past ``DELTA_CHAIN_MAX`` deltas.
 
     ``stats`` (an ``InvocationStats``) receives the last transaction's.
-    Returns the plan's stats plus ``txns`` and ``base`` (the version the
-    delta is chained to, or None)."""
+    Returns the plan's stats plus ``txns``, ``version`` (the one written)
+    and ``base`` (the version the delta is chained to, or None)."""
     base = name if base is None else base
     found: Dict[str, Any] = {}
     if plan_kw.get("baseline") is not None or plan_kw.get("dirty_blocks"):
@@ -407,8 +408,9 @@ def save_tree(
         runtime.invoke(resolve, read_only=False)
     version = found.get("version")
     meta = found.get("meta", {})
-    depth = meta.get("depth", 0) + 1
-    if version in (None, base) or depth > DELTA_CHAIN_MAX or \
+    # only a version carries a depth: an in-place save can be rewritten
+    depth = meta.get("depth", DELTA_CHAIN_MAX) + 1
+    if depth > DELTA_CHAIN_MAX or \
             meta.get("block_bytes") != plan_kw.get("block_bytes",
                                                    TENSOR_BLOCK_BYTES):
         plan_kw.pop("baseline", None)
@@ -431,7 +433,7 @@ def save_tree(
     for i, part in enumerate(parts):
         last = i == len(parts) - 1
         runtime.invoke(write, part, last, stats=stats if last else None)
-    return dict(plan.stats, txns=len(parts), base=version)
+    return dict(plan.stats, txns=len(parts), version=target, base=version)
 
 
 def unflatten_like(template: PyTree, flat: Dict[str, np.ndarray]) -> PyTree:

@@ -33,8 +33,10 @@ def publish(local: LocalServer, params: Any, *,
             baseline: Optional[Dict[str, np.ndarray]] = None) -> Dict[str, int]:
     """Commit ``{"params": params}`` as a new version of the served state
     (``save_tree``): a replica refreshing meanwhile loads the old version
-    or the new one whole. ``baseline`` (the flat leaves last published)
-    writes only the changed blocks, compared at the backend's block size."""
+    or the new one whole. ``baseline`` (the flat leaves of the version the
+    served state holds, i.e. the last publish when this caller is the only
+    publisher) writes only the changed blocks, compared at the backend's
+    block size."""
     return save_tree(runtime_for(local), ROOT, NAME, {"params": params},
                      baseline=baseline, block_bytes=local.backend.block_size)
 

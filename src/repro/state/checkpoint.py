@@ -120,8 +120,10 @@ class CheckpointManager:
     ``restore`` be served from the lease tier's bounded-staleness view.
 
     A delta save of step N writes a version of ``step_N`` chained to the
-    previous step's version (``save_tree`` ``base``): only its dirty
-    blocks are stored, and a restore reads the rest from the chain."""
+    version this manager saved last (``save_tree`` ``base``), the one its
+    dirty blocks were found against, whatever other writers saved since:
+    only those blocks are stored, and a restore reads the rest from the
+    chain."""
 
     def __init__(
         self,
@@ -143,6 +145,8 @@ class CheckpointManager:
         self.dirty_impl = dirty_impl
         self._baseline: Dict[str, np.ndarray] = {}
         self._last_step: Optional[int] = None
+        #: the version the last save wrote, which ``_baseline`` holds
+        self._last_version: Optional[str] = None
 
     # ------------------------------------------------------------------ #
     def save(self, step: int, state: PyTree, *, delta_from_last: bool = True) -> SaveInfo:
@@ -150,9 +154,9 @@ class CheckpointManager:
         leaves = flatten_with_names(state)
         dirty: Optional[Dict[str, List[int]]] = None
         base = None
-        if delta_from_last and self._last_step is not None \
+        if delta_from_last and self._last_version is not None \
                 and self._last_step != step:
-            base = f"step_{self._last_step}"
+            base = self._last_version
             # dirty detection happens ONCE, outside the transaction:
             # a Conflict restart re-runs only the block writes
             dirty = {}
@@ -184,7 +188,7 @@ class CheckpointManager:
         )
         self._baseline = {}  # release the old one before taking the new
         self._baseline = {n: a if _frozen(a) else a.copy() for n, a in leaves}
-        self._last_step = step
+        self._last_step, self._last_version = step, stats["version"]
         return SaveInfo(
             step=step,
             commit_ts=inv.commit_ts,

@@ -184,6 +184,21 @@ def test_resaving_a_step_keeps_the_steps_chained_to_it(local):
         np.testing.assert_array_equal(got["w"], want["w"])
 
 
+def test_a_delta_chains_to_the_version_its_blocks_were_found_against(local):
+    """Another writer re-saving the previous step between two saves does
+    not change what the next delta is compared with or chained to."""
+    cm = CheckpointManager(local, block_bytes=4096)
+    s1 = {"w": np.arange(4096, dtype=np.float32)}
+    cm.save(1, s1)
+    other = CheckpointManager(LocalServer(local.backend), block_bytes=4096)
+    other.save(1, {"w": s1["w"] + 10.0})
+    s2 = {"w": s1["w"].copy()}
+    s2["w"][0] = -1.0
+    assert cm.save(2, s2).dirty == {"w": [0]}
+    got, _ = cm.restore({"w": None}, step=2)
+    np.testing.assert_array_equal(got["w"], s2["w"])
+
+
 def test_delta_chain_is_cut_by_a_full_save():
     from repro.core.tensorstate import DELTA_CHAIN_MAX
 
