@@ -19,8 +19,14 @@ Completed spans are recorded into a per-process ring buffer
 (``chrome_trace``), so a whole `FunctionRuntime` invocation — client
 RPCs, server queue/exec, WAL fsyncs, Conflict-restart chains — renders
 as one timeline in Perfetto (https://ui.perfetto.dev, "Open trace
-file"). Timestamps are CLOCK_MONOTONIC microseconds, comparable across
-processes on one machine.
+file"). A span records under a trace context, or while JAX's profiler
+captures: then a span with no context starts a trace of its own, and
+each ``span`` also enters a ``jax.profiler.TraceAnnotation`` of its
+name. Ring timestamps are CLOCK_MONOTONIC microseconds (the clock of
+``time.monotonic`` and ``time.perf_counter``), comparable across
+processes on one machine; the profiler's trace has its own time base
+(offsets within the profile), and the annotations are what place a
+span on it, beside the device's operations.
 
 **Logging** — a tiny leveled logger emitting structured ``key=value``
 lines to stderr (never stdout: the ``LISTENING`` / ``SHUTDOWN clean``
@@ -46,7 +52,7 @@ __all__ = [
     "MetricsRegistry", "SpanRecorder", "SlowOpLog", "Logger",
     "REGISTRY", "SPANS", "SLOW_OPS", "LOG",
     "now_us", "new_trace_id", "new_span_id",
-    "current_trace", "set_trace", "span",
+    "current_trace", "set_trace", "span", "profiler_annotation",
     "chrome_trace", "render_prometheus", "serve_metrics",
     "LATENCY_BUCKETS_US", "PUSH_BUCKETS_US", "SIZE_BUCKETS",
 ]
@@ -389,7 +395,13 @@ def crash_point(name: str) -> None:
 # --------------------------------------------------------------------------- #
 # tracing
 # --------------------------------------------------------------------------- #
-_tls = threading.local()
+class _Local(threading.local):
+    #: a class default, so reading an unset context raises nothing inside
+    #: (a missing thread-local attribute costs an exception per read)
+    trace: Optional[Tuple[int, int]] = None
+
+
+_tls = _Local()
 
 
 def new_trace_id() -> int:
@@ -402,25 +414,40 @@ def new_span_id() -> int:
 
 def current_trace() -> Optional[Tuple[int, int]]:
     """The thread's active ``(trace_id, span_id)``, or None. One
-    thread-local getattr — cheap enough for RPC hot paths."""
-    return getattr(_tls, "trace", None)
+    thread-local attribute read — cheap enough for RPC hot paths."""
+    return _tls.trace
 
 
 def set_trace(ctx: Optional[Tuple[int, int]]) -> Optional[Tuple[int, int]]:
     """Install (or clear, with None) the thread's trace context.
     Returns the previous context so callers can restore it."""
-    prev = getattr(_tls, "trace", None)
+    prev = _tls.trace
     _tls.trace = ctx
     return prev
 
 
+def profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` while JAX's profiler captures,
+    else None. JAX is looked up, never imported, so this module stays
+    stdlib-only and a process without JAX (the server) pays one dict
+    lookup."""
+    prof = getattr(sys.modules.get("jax"), "profiler", None)
+    if prof is None:
+        return None
+    ann = prof.TraceAnnotation
+    return ann if ann.is_enabled() else None
+
+
 class SpanRecorder:
     """Per-process ring buffer of completed spans (plain dicts, wire-
-    codec-safe). Bounded: old spans fall off; tracing can stay on."""
+    codec-safe). Bounded: old spans fall off, counted in ``dropped``;
+    tracing can stay on."""
 
     def __init__(self, capacity: int = 8192) -> None:
         self._buf: deque = deque(maxlen=capacity)
         self._lock = threading.Lock()
+        #: spans pushed out of the full ring since it was last cleared
+        self.dropped = 0
 
     def record(self, name: str, cat: str, trace_id: int, span_id: int,
                t0_us: int, dur_us: int, parent_id: int = 0,
@@ -433,6 +460,8 @@ class SpanRecorder:
         if args:
             rec["ar"] = args
         with self._lock:
+            if len(self._buf) == self._buf.maxlen:
+                self.dropped += 1
             self._buf.append(rec)
 
     def spans(self, trace_id: Optional[int] = None,
@@ -441,6 +470,7 @@ class SpanRecorder:
             out = list(self._buf)
             if clear:
                 self._buf.clear()
+                self.dropped = 0
         if trace_id is not None:
             out = [s for s in out if s["tr"] == trace_id]
         return out
@@ -448,6 +478,7 @@ class SpanRecorder:
     def clear(self) -> None:
         with self._lock:
             self._buf.clear()
+            self.dropped = 0
 
 
 class span:
@@ -456,29 +487,51 @@ class span:
         with obs.span("rpc.commit", "client"):
             ...
 
-    Uses the thread's current trace context; records nothing when no
-    trace is active (the off path is one getattr + one branch). Child
-    spans get a fresh span id with the enclosing span as parent, and
-    install themselves as the thread context for the duration."""
+    Records under the thread's trace context, or while JAX's profiler
+    captures (``profiler_annotation``); otherwise it records nothing (the
+    off path is one thread-local read, one ``sys.modules`` lookup and one
+    ``is_enabled()``). A span with no enclosing context starts a trace of
+    its own, as does one opened with ``root=True`` (which records even
+    with neither). Child spans get a fresh span id with the enclosing span
+    as parent, and install themselves as the thread context for the
+    duration, so RPCs inside carry it to the server (``wire.FLAG_TRACE``).
+    While the profiler captures, the span also enters a
+    ``TraceAnnotation`` of its name, which places it in the profiler's
+    trace on the device's clock."""
 
-    __slots__ = ("name", "cat", "args", "recorder", "_t0", "_ctx", "_prev")
+    __slots__ = ("name", "cat", "args", "recorder", "root",
+                 "_t0", "_ctx", "_prev", "_ann")
 
     def __init__(self, name: str, cat: str = "",
                  args: Optional[Dict[str, Any]] = None,
-                 recorder: Optional[SpanRecorder] = None):
+                 recorder: Optional[SpanRecorder] = None, root: bool = False):
         self.name = name
         self.cat = cat
         self.args = args
         self.recorder = recorder
+        self.root = root
+        self._ctx: Optional[Tuple[int, int, int]] = None
+
+    @property
+    def trace_id(self) -> int:
+        """The span's trace id while it records (0 when it does not)."""
+        return self._ctx[0] if self._ctx is not None else 0
 
     def __enter__(self):
         cur = current_trace()
-        if cur is None:
+        ann = profiler_annotation()
+        if cur is None and ann is None and not self.root:
             self._ctx = None
             return self
-        tid, parent = cur
+        if cur is None or self.root:
+            tid, parent = new_trace_id(), 0
+        else:
+            tid, parent = cur
         self._ctx = (tid, new_span_id(), parent)
         self._prev = set_trace(self._ctx[:2])
+        self._ann = ann(self.name) if ann is not None else None
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = now_us()
         return self
 
@@ -486,10 +539,13 @@ class span:
         ctx = self._ctx
         if ctx is None:
             return False
+        dur = now_us() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         set_trace(self._prev)
         tid, sid, parent = ctx
         (self.recorder or SPANS).record(
-            self.name, self.cat, tid, sid, self._t0, now_us() - self._t0,
+            self.name, self.cat, tid, sid, self._t0, dur,
             parent_id=parent, args=self.args,
         )
         return False
@@ -600,10 +656,8 @@ class SlowOpLog:
 # process-wide defaults
 # --------------------------------------------------------------------------- #
 REGISTRY = MetricsRegistry()
-SPANS = SpanRecorder()
+#: sized so that a benchmark's traced window (51 s of the ML state path
+#: under the profiler) drops no span; see docs/observability.md
+SPANS = SpanRecorder(capacity=1 << 16)
 SLOW_OPS = SlowOpLog()
 LOG = Logger()
-
-
-def get_registry() -> MetricsRegistry:
-    return REGISTRY

@@ -85,7 +85,9 @@ class InvocationStats:
     #: one entry per abort: {"tag", "key", "shard"?, "winner"?} dicts
     #: explaining WHAT conflicted (paper §3.3's restart loop, made visible)
     abort_reasons: List[Dict[str, Any]] = field(default_factory=list)
-    #: trace id (nonzero when the runtime ran with tracing on)
+    #: trace id of the invocation's span (nonzero when it recorded: the
+    #: runtime ran with ``trace=True``, under a trace context, or while
+    #: JAX's profiler captured)
     trace_id: int = 0
 
 
@@ -244,29 +246,20 @@ class FunctionRuntime:
         self.stats.invocations += 1
         # one trace id spans the WHOLE invocation, Conflict restarts
         # included — every retry attempt renders on the same Perfetto
-        # timeline (see docs/observability.md)
-        trace_prev: Any = None
-        trace_ctx: Any = None
-        inv_t0 = 0
+        # timeline (see docs/observability.md). ``trace=True`` makes the
+        # invocation a root of its own; otherwise it records as any span
+        # does: under an enclosing context, or while JAX's profiler runs
         name = getattr(body, "__name__", "faas_function")
-        if self.trace:
-            trace_ctx = (obs.new_trace_id(), obs.new_span_id())
-            trace_prev = obs.set_trace(trace_ctx)
-            inv_t0 = obs.now_us()
-            if stats:
-                stats.trace_id = trace_ctx[0]
+        root = obs.span(f"invoke.{name}", "runtime", root=self.trace)
         try:
-            return self._invoke_loop(
-                body, faas, args, kwargs, ro, inferred, max_retries,
-                stats, t0, name,
-            )
-        finally:
-            if self.trace:
-                obs.SPANS.record(
-                    f"invoke.{name}", "runtime", trace_ctx[0], trace_ctx[1],
-                    inv_t0, obs.now_us() - inv_t0,
+            with root:
+                if stats and root.trace_id:
+                    stats.trace_id = root.trace_id
+                return self._invoke_loop(
+                    body, faas, args, kwargs, ro, inferred, max_retries,
+                    stats, t0, name,
                 )
-                obs.set_trace(trace_prev)
+        finally:
             # warm-container cache health: sampled once per invocation,
             # never on the block fetch path
             cs = self.local.cache_stats()
@@ -322,7 +315,8 @@ class FunctionRuntime:
                     txn.abort()
                     raise
                 try:
-                    ts = txn.commit()
+                    with obs.span("invoke.commit", "runtime"):
+                        ts = txn.commit()
                 except Conflict as c:
                     last = c
                     self.stats.aborts += 1

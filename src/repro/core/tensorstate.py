@@ -39,6 +39,7 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
 
+from repro.core import obs
 from repro.core.arena import BlockArena, default_arena
 from repro.core.posix import FaaSFS, O_CREAT, O_TRUNC
 from repro.core.types import TENSOR_BLOCK_BYTES, NotFound
@@ -310,6 +311,10 @@ class TensorStore:
         arena buffers; they stay valid as long as any view is alive and
         the backing buffer is recycled when the last one dies. Callers
         that need to mutate must ``.copy()``."""
+        with obs.span("tensor.load", "tensor"):
+            return self._load(name, zero_copy)
+
+    def _load(self, name: str, zero_copy: bool) -> Dict[str, np.ndarray]:
         name, meta = self.resolve(name)
         metas = {name: meta}
         # tensor-sized readahead: the meta layout names every leaf, so
@@ -330,34 +335,35 @@ class TensorStore:
                 if leaf["shape"] else 1
             n = count * dt.itemsize
             srcs = self._sources(name, leaf, n, metas)
-            if arena is not None:
-                buf = arena.alloc(n, round_to=self.fs.txn.block_size)
-                for src, spans in srcs:
-                    fd = self.fs.open(self._leaf_path(src, leaf["name"]))
-                    for lo, hi in spans:
-                        # block-aligned capacity: every block in the span
-                        # is a full-size sink destination, incl. the tail
-                        self.fs.pread_into(fd, hi - lo, lo,
-                                           buf.view(lo, buf.capacity - lo))
-                    self.fs.close(fd)
-                mv = buf.seal()
-                root = np.frombuffer(mv, dtype=dt, count=count)
-                # recycle the buffer when the last aliasing view dies:
-                # every numpy view of ``root`` keeps ``root`` alive
-                # (base chains collapse to the owning array), so this
-                # fires only once nothing can read the memory
-                weakref.finalize(root, buf.release)
-                out[leaf["name"]] = root.reshape(leaf["shape"])
-            else:
-                data = bytearray(n)
-                for src, spans in srcs:
-                    fd = self.fs.open(self._leaf_path(src, leaf["name"]))
-                    for lo, hi in spans:
-                        data[lo:hi] = self.fs.pread(fd, hi - lo, lo)
-                    self.fs.close(fd)
-                out[leaf["name"]] = np.frombuffer(
-                    data, dtype=dt, count=count
-                ).reshape(leaf["shape"])
+            with obs.span("tensor.land", "tensor", args={"bytes": n}):
+                if arena is not None:
+                    buf = arena.alloc(n, round_to=self.fs.txn.block_size)
+                    for src, spans in srcs:
+                        fd = self.fs.open(self._leaf_path(src, leaf["name"]))
+                        for lo, hi in spans:
+                            # block-aligned capacity: every block in the span
+                            # is a full-size sink destination, incl. the tail
+                            self.fs.pread_into(fd, hi - lo, lo,
+                                               buf.view(lo, buf.capacity - lo))
+                        self.fs.close(fd)
+                    mv = buf.seal()
+                    root = np.frombuffer(mv, dtype=dt, count=count)
+                    # recycle the buffer when the last aliasing view dies:
+                    # every numpy view of ``root`` keeps ``root`` alive
+                    # (base chains collapse to the owning array), so this
+                    # fires only once nothing can read the memory
+                    weakref.finalize(root, buf.release)
+                    out[leaf["name"]] = root.reshape(leaf["shape"])
+                else:
+                    data = bytearray(n)
+                    for src, spans in srcs:
+                        fd = self.fs.open(self._leaf_path(src, leaf["name"]))
+                        for lo, hi in spans:
+                            data[lo:hi] = self.fs.pread(fd, hi - lo, lo)
+                        self.fs.close(fd)
+                    out[leaf["name"]] = np.frombuffer(
+                        data, dtype=dt, count=count
+                    ).reshape(leaf["shape"])
         if arena is not None:
             arena.note_fill(txn.bytes_sunk - sunk0)
             arena.note_copy(txn.bytes_copied_into - copied0)
@@ -416,14 +422,16 @@ def save_tree(
         plan_kw.pop("baseline", None)
         plan_kw.pop("dirty_blocks", None)
         version, depth = None, 0
-    plan = plan_save(tree, base=version, **plan_kw)
-    plan.meta["depth"] = depth
-    parts = plan.parts(TXN_WRITE_BYTES)
+    with obs.span("tensor.plan", "tensor"):
+        plan = plan_save(tree, base=version, **plan_kw)
+        plan.meta["depth"] = depth
+        parts = plan.parts(TXN_WRITE_BYTES)
     target = f"{name}@{secrets.token_hex(8)}"
 
     def write(fs: FaaSFS, part, last: bool) -> None:
         store = TensorStore(fs, prefix=prefix)
-        store.write_part(target, part)
+        with obs.span("tensor.stage", "tensor"):
+            store.write_part(target, part)
         if last:
             store.write_meta(target, plan.meta)
             store.write_meta(name, {"ref": target})

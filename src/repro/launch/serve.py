@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig, get_config, list_configs, reduced_config
+from repro.core import obs
 from repro.core.backend import BackendService
 from repro.core.client import LocalServer
 from repro.core.runtime import runtime_for
@@ -52,15 +53,22 @@ def make_decode_fn(cfg: ModelConfig, tokens: int) -> Callable[[Any, np.ndarray],
         return M.decode_step(cfg, p, cache, tok, idx)
 
     def decode_fn(state, prompts):
-        p = jax.tree.map(jnp.asarray, state["params"])
-        cache = M.make_decode_cache(cfg, prompts.shape[0], max_len)
-        toks = jnp.asarray(prompts[:, :1])
-        out = [np.asarray(toks)]
-        for i in range(tokens):
-            logits, cache = decode_one(p, cache, toks, jnp.int32(i))
-            toks = jnp.argmax(logits, axis=-1)[:, None]
-            out.append(np.asarray(toks))
-        return np.concatenate(out, axis=1)
+        with obs.span("replica.upload", "replica") as up:
+            p = jax.tree.map(jnp.asarray, state["params"])
+            if up.trace_id:
+                # while the span records, end it when the weights are on the
+                # device; the first decode step waits on these same arrays
+                # anyway, and untraced the host goes on to dispatch it
+                p = jax.block_until_ready(p)
+        with obs.span("replica.decode", "replica"):
+            cache = M.make_decode_cache(cfg, prompts.shape[0], max_len)
+            toks = jnp.asarray(prompts[:, :1])
+            out = [np.asarray(toks)]
+            for i in range(tokens):
+                logits, cache = decode_one(p, cache, toks, jnp.int32(i))
+                toks = jnp.argmax(logits, axis=-1)[:, None]
+                out.append(np.asarray(toks))
+            return np.concatenate(out, axis=1)
 
     return decode_fn
 

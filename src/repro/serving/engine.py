@@ -3,17 +3,15 @@
 A serving replica pins a committed version (the paper's snapshot reads):
 requests are served from a consistent parameter snapshot even while training
 transactions keep committing. ``refresh()`` advances to the newest committed
-version, pulling only changed blocks (fine-grained cache updates) — the
-serving-side analogue of delta checkpoint restore.
+version by reloading the whole state zero-copy (``TensorStore.load``): every
+block lands in an arena buffer again, changed or not.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, Optional
 
-import numpy as np
-
+from repro.core import obs
 from repro.core.client import LocalServer
 from repro.core.posix import FaaSFS
 from repro.core.runtime import runtime_for
@@ -25,10 +23,7 @@ PyTree = Any
 @dataclass
 class ServeStats:
     requests: int = 0
-    tokens: int = 0
     refreshes: int = 0
-    refresh_bytes: int = 0
-    wall_s: float = 0.0
 
 
 class SnapshotServer:
@@ -54,30 +49,25 @@ class SnapshotServer:
 
     # ------------------------------------------------------------------ #
     def refresh(self) -> int:
-        """Load (or delta-update to) the latest committed snapshot."""
+        """Load the latest committed snapshot, whole and zero-copy."""
         holder: Dict[str, Any] = {}
-        before = self.local.misses
 
         def do_read(fs: FaaSFS) -> None:
             store = TensorStore(fs, prefix=self.root)
             holder["flat"] = store.load(self.name, zero_copy=True)
             holder["ts"] = fs.txn.read_ts
 
-        runtime_for(self.local).invoke(do_read, read_only=True)
-        self.params = unflatten_like(self.template, holder["flat"])
+        with obs.span("replica.refresh", "replica"):
+            runtime_for(self.local).invoke(do_read, read_only=True)
+            self.params = unflatten_like(self.template, holder["flat"])
         self.version = holder["ts"]
         self.stats.refreshes += 1
-        self.stats.refresh_bytes += (
-            (self.local.misses - before) * self.local.backend.block_size
-        )
         return self.version
 
     # ------------------------------------------------------------------ #
     def serve(self, batch: Any) -> Any:
         if self.params is None:
             self.refresh()
-        t0 = time.perf_counter()
         out = self.decode_fn(self.params, batch)
         self.stats.requests += 1
-        self.stats.wall_s += time.perf_counter() - t0
         return out

@@ -33,6 +33,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro.core import obs
 from repro.core.client import LocalServer
 from repro.core.posix import FaaSFS, O_CREAT, O_TRUNC
 from repro.core.runtime import FunctionRuntime, InvocationStats, runtime_for
@@ -150,6 +151,10 @@ class CheckpointManager:
 
     # ------------------------------------------------------------------ #
     def save(self, step: int, state: PyTree, *, delta_from_last: bool = True) -> SaveInfo:
+        with obs.span("ckpt.save", "ckpt"):
+            return self._save(step, state, delta_from_last)
+
+    def _save(self, step: int, state: PyTree, delta_from_last: bool) -> SaveInfo:
         t0 = time.perf_counter()
         leaves = flatten_with_names(state)
         dirty: Optional[Dict[str, List[int]]] = None
@@ -159,16 +164,17 @@ class CheckpointManager:
             base = self._last_version
             # dirty detection happens ONCE, outside the transaction:
             # a Conflict restart re-runs only the block writes
+            pairs = [(n, a, self._baseline[n]) for n, a in leaves
+                     if n in self._baseline]
             dirty = {}
-            for lname, arr in leaves:
-                old = self._baseline.get(lname)
-                if old is None:
-                    continue
-                idx = dirty_block_indices(
-                    arr, old, self.block_bytes, self.dirty_impl
-                )
-                if idx is not None:
-                    dirty[lname] = idx
+            with obs.span("ckpt.detect", "ckpt", args={
+                    "leaves": len(pairs), "bytes": sum(a.nbytes for _, a, _ in pairs)}):
+                for lname, arr, old in pairs:
+                    idx = dirty_block_indices(
+                        arr, old, self.block_bytes, self.dirty_impl
+                    )
+                    if idx is not None:
+                        dirty[lname] = idx
 
         def flip_latest(fs: FaaSFS) -> None:
             # atomically flip the latest pointer (POSIX rename semantics)
@@ -181,13 +187,15 @@ class CheckpointManager:
             fs.rename(tmp, f"{self.root}/latest")
 
         inv = InvocationStats()
-        stats = save_tree(
-            self.runtime, self.root, f"step_{step}", state,
-            finish=flip_latest, stats=inv, block_bytes=self.block_bytes,
-            dirty_blocks=dirty, base=base,
-        )
-        self._baseline = {}  # release the old one before taking the new
-        self._baseline = {n: a if _frozen(a) else a.copy() for n, a in leaves}
+        with obs.span("ckpt.commit", "ckpt"):
+            stats = save_tree(
+                self.runtime, self.root, f"step_{step}", state,
+                finish=flip_latest, stats=inv, block_bytes=self.block_bytes,
+                dirty_blocks=dirty, base=base,
+            )
+        with obs.span("ckpt.baseline", "ckpt"):
+            self._baseline = {}  # release the old one before taking the new
+            self._baseline = {n: a if _frozen(a) else a.copy() for n, a in leaves}
         self._last_step, self._last_version = step, stats["version"]
         return SaveInfo(
             step=step,
@@ -228,15 +236,16 @@ class CheckpointManager:
         over arena buffers filled straight off the wire — ``.copy()``
         any leaf you need to mutate in place (functional updates, the
         normal jax style, need nothing)."""
-        if step is None:
-            step = self.latest_step()
+        with obs.span("ckpt.restore", "ckpt"):
             if step is None:
-                raise FileNotFoundError("no checkpoint committed yet")
-        holder: Dict[str, Any] = {}
+                step = self.latest_step()
+                if step is None:
+                    raise FileNotFoundError("no checkpoint committed yet")
+            holder: Dict[str, Any] = {}
 
-        def do_load(fs: FaaSFS) -> None:
-            store = TensorStore(fs, prefix=self.root)
-            holder["flat"] = store.load(f"step_{step}", zero_copy=zero_copy)
+            def do_load(fs: FaaSFS) -> None:
+                store = TensorStore(fs, prefix=self.root)
+                holder["flat"] = store.load(f"step_{step}", zero_copy=zero_copy)
 
-        self.runtime.invoke(do_load, read_only=True)
-        return unflatten_like(template, holder["flat"]), step
+            self.runtime.invoke(do_load, read_only=True)
+            return unflatten_like(template, holder["flat"]), step

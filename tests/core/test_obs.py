@@ -1,9 +1,13 @@
 """core/obs unit tests: registry semantics, histogram bucket edges,
 the hot-path identity contract (no per-op label joins), snapshots under
-concurrent increments, Prometheus rendering, spans, and the logger."""
+concurrent increments, Prometheus rendering, spans (and their bridge to
+JAX's profiler), and the logger."""
+import glob
 import io
 import json
+import os
 import threading
+import time
 import urllib.request
 
 import pytest
@@ -204,6 +208,157 @@ def test_span_ring_is_bounded():
     assert len(got) == 8 and got[0]["n"] == "s12"
     rec.spans(clear=True)
     assert rec.spans() == []
+
+
+def test_span_ring_counts_drops():
+    rec = SpanRecorder(capacity=4)
+    for i in range(10):
+        rec.record(f"s{i}", "t", 1, i + 1, 0, 1)
+    assert rec.dropped == 6 and len(rec.spans()) == 4
+    rec.clear()
+    assert rec.dropped == 0
+    for i in range(5):
+        rec.record(f"s{i}", "t", 1, i + 1, 0, 1)
+    assert rec.spans(clear=True) and rec.dropped == 0
+
+
+def test_perf_counter_and_now_us_read_one_clock():
+    # benchmark spans (time.perf_counter) and ring spans (now_us) are
+    # compared directly: they must be the same clock, not merely monotonic
+    assert time.get_clock_info("perf_counter").implementation == \
+        time.get_clock_info("monotonic").implementation
+    for _ in range(100):
+        a = time.perf_counter_ns() // 1000
+        u = obs.now_us()
+        b = time.perf_counter_ns() // 1000
+        assert a <= u <= b
+
+
+def test_span_off_path_records_nothing_and_frames_carry_no_envelope(
+        tmp_path, monkeypatch):
+    from repro.core import remote, wire
+    from repro.core.backend import BackendService
+    from repro.core.client import LocalServer
+    from repro.core.posix import O_CREAT, O_RDWR
+    from repro.core.runtime import FunctionRuntime
+    from repro.core.server import BackendServer
+
+    assert obs.profiler_annotation() is None
+    ring = SpanRecorder()
+    monkeypatch.setattr(obs, "SPANS", ring)
+    frames = []
+    real = wire.encode_frame_into
+
+    def spy(out, msg_type, o, req_id=0, trace=None):
+        at = len(out)
+        n = real(out, msg_type, o, req_id, trace)
+        frames.append((msg_type, o, req_id, trace, bytes(out[at:at + n])))
+        return n
+
+    monkeypatch.setattr(remote.wire, "encode_frame_into", spy)
+    server = BackendServer(BackendService(block_size=64),
+                           wal_path=str(tmp_path / "wal")).start()
+    rb = remote.RemoteBackend("127.0.0.1", server.port)
+    try:
+        rt = FunctionRuntime(LocalServer(rb))
+
+        def put(fs):
+            fd = fs.open("/mnt/tsfs/f", O_CREAT | O_RDWR)
+            fs.pwrite(fd, b"x" * 100, 0)
+
+        with obs.span("outer", "test"):
+            assert obs.current_trace() is None
+            rt.invoke(put)
+    finally:
+        rb.close()
+        server.shutdown()
+    monkeypatch.undo()
+    assert ring.spans() == [] and ring.dropped == 0
+    assert frames and all(t is None for *_, t, _ in frames)
+    for msg_type, o, req_id, _, raw in frames:
+        _, _, _, flags = wire.decode_header_ex(raw)
+        assert flags == 0
+        assert raw == wire.encode_frame(msg_type, o, req_id)
+
+
+def _xplane_host_events(log_dir):
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(os.path.join(
+        log_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    return [(ev.name, ev.start_ns, ev.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if not plane.name.startswith("/device:")
+            for line in plane.lines for ev in line.events]
+
+
+def test_span_under_profiler_roots_a_trace_on_the_device_clock(
+        tmp_path, monkeypatch):
+    jax = pytest.importorskip("jax")
+
+    ring = SpanRecorder()
+    monkeypatch.setattr(obs, "SPANS", ring)
+    assert obs.current_trace() is None
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert obs.profiler_annotation() is not None
+        with obs.span("probe.outer", "test") as outer:
+            ctx = obs.current_trace()
+            assert ctx is not None and ctx[0] == outer.trace_id
+            with obs.span("probe.inner", "test", args={"bytes": 8}):
+                time.sleep(0.02)
+        assert obs.current_trace() is None  # the root uninstalls itself
+        with obs.span("probe.other", "test") as other:
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    assert obs.profiler_annotation() is None
+
+    by = {s["n"]: s for s in ring.spans()}
+    o, i = by["probe.outer"], by["probe.inner"]
+    assert o["pa"] == 0 and o["tr"] == outer.trace_id != 0
+    assert i["tr"] == o["tr"] and i["pa"] == o["sp"] and i["ar"] == {"bytes": 8}
+    assert by["probe.other"]["tr"] == other.trace_id != o["tr"]  # fresh root
+
+    ev = {n: (t, d) for n, t, d in _xplane_host_events(str(tmp_path))
+          if n.startswith("probe.")}
+    (ot, od), (it, idur) = ev["probe.outer"], ev["probe.inner"]
+    assert ot <= it and it + idur <= ot + od  # nested in the xplane
+    assert abs(od / 1e3 - o["du"]) < 1000 and abs(idur / 1e3 - i["du"]) < 1000
+
+
+def test_runtime_root_records_under_profiler_without_trace_flag(
+        tmp_path, monkeypatch):
+    jax = pytest.importorskip("jax")
+    from repro.core.backend import BackendService
+    from repro.core.client import LocalServer
+    from repro.core.posix import O_CREAT, O_RDWR
+    from repro.core.runtime import FunctionRuntime, InvocationStats
+
+    ring = SpanRecorder()
+    monkeypatch.setattr(obs, "SPANS", ring)
+    rt = FunctionRuntime(LocalServer(BackendService(block_size=64)))
+    assert not rt.trace
+
+    def put(fs):
+        fd = fs.open("/mnt/tsfs/g", O_CREAT | O_RDWR)
+        fs.pwrite(fd, b"y" * 10, 0)
+
+    rt.invoke(put)
+    assert ring.spans() == []  # no context, no profiler: nothing
+    stats = InvocationStats()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        rt.invoke(put, stats=stats)
+    finally:
+        jax.profiler.stop_trace()
+    spans = ring.spans()
+    root = next(s for s in spans if s["n"] == "invoke.put")
+    assert root["pa"] == 0 and root["tr"] == stats.trace_id != 0
+    attempt = next(s for s in spans if s["n"] == "invoke.attempt")
+    commit = next(s for s in spans if s["n"] == "invoke.commit")
+    assert attempt["pa"] == root["sp"] and commit["pa"] == attempt["sp"]
+    assert {s["tr"] for s in spans} == {root["tr"]}
 
 
 # ------------------------------------------------------------------------- #

@@ -276,3 +276,57 @@ def test_kernel_detector_sees_changes_that_square_to_zero(change):
     auto = dirty_block_indices(new, old, 4096, "auto")
     kern = dirty_block_indices(new, old, 4096, "pallas_interpret")
     assert auto == kern == want
+
+
+def _tree(ring):
+    """{name: [span, ...]} and a parent-name lookup over a ring's spans."""
+    spans = ring.spans()
+    by_id = {s["sp"]: s for s in spans}
+    names = {}
+    for s in spans:
+        names.setdefault(s["n"], []).append(s)
+    return names, lambda s: by_id.get(s["pa"], {}).get("n")
+
+
+def test_save_and_restore_record_their_span_tree_under_profiler(tmp_path, monkeypatch):
+    jax = pytest.importorskip("jax")
+    from repro.core import obs
+
+    ring = obs.SpanRecorder()
+    monkeypatch.setattr(obs, "SPANS", ring)
+    cm = CheckpointManager(LocalServer(BackendService(block_size=512)),
+                           block_bytes=512, dirty_impl="xla")
+    s = state(1.0)
+    cm.save(1, s)
+    assert ring.spans() == []  # no profiler, no context: nothing recorded
+    s["params"]["w"] = s["params"]["w"].copy()
+    s["params"]["w"][3, 3] = 9.0
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        info = cm.save(2, s)
+        cm.restore(state(), 2)
+    finally:
+        jax.profiler.stop_trace()
+    assert info.dirty is not None
+    names, parent = _tree(ring)
+    save, = names["ckpt.save"]
+    assert save["pa"] == 0
+    for child in ("ckpt.detect", "ckpt.commit", "ckpt.baseline"):
+        sp, = names[child]
+        assert parent(sp) == "ckpt.save" and sp["tr"] == save["tr"]
+    detect, = names["ckpt.detect"]
+    assert detect["ar"] == {"leaves": 3, "bytes": 2 * 32 * 32 * 4 + 4}
+    assert parent(names["tensor.plan"][0]) == "ckpt.commit"
+    stage, = names["tensor.stage"]
+    assert parent(stage) == "invoke.attempt"
+    attempt = next(a for a in names["invoke.attempt"] if a["sp"] == stage["pa"])
+    assert parent(attempt) == "invoke.write"
+    assert any(parent(c) == "invoke.attempt" and c["pa"] == attempt["sp"]
+               for c in names["invoke.commit"])
+    restore, = names["ckpt.restore"]
+    assert restore["pa"] == 0 and restore["tr"] != save["tr"]
+    load, = names["tensor.load"]
+    assert load["tr"] == restore["tr"]
+    lands = names["tensor.land"]
+    assert len(lands) == 3 and all(parent(x) == "tensor.load" for x in lands)
+    assert sorted(x["ar"]["bytes"] for x in lands) == [4, 4096, 4096]

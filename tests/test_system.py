@@ -150,3 +150,42 @@ def test_two_workers_shared_state_makes_progress(tiny_setup):
     final = trainers[0].read_state()
     # every committed step counted once despite conflicts
     assert int(np.asarray(final["opt"]["count"])) == 6
+
+
+def test_replica_records_refresh_upload_and_decode_spans_under_profiler(
+        tiny_setup, tmp_path, monkeypatch):
+    from repro.core import obs
+    from repro.launch.serve import make_decode_fn, publish
+
+    cfg, state0, _, _ = tiny_setup
+    ring = obs.SpanRecorder()
+    monkeypatch.setattr(obs, "SPANS", ring)
+    be = BackendService(block_size=4096)
+    publish(LocalServer(be), np_state(state0)["params"])
+    srv = SnapshotServer(LocalServer(be), make_decode_fn(cfg, 2),
+                         {"params": np_state(state0)["params"]})
+    prompts = np.zeros((2, 1), np.int32)
+    srv.refresh()
+    srv.serve(prompts)  # compiles outside the trace
+    assert ring.spans() == []
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        srv.refresh()
+        srv.serve(prompts)
+    finally:
+        jax.profiler.stop_trace()
+    spans = ring.spans()
+    by_id = {s["sp"]: s for s in spans}
+    parent = lambda s: by_id.get(s["pa"], {}).get("n")  # noqa: E731
+    names = [s["n"] for s in spans]
+    assert names.count("replica.refresh") == 1
+    assert names.count("replica.upload") == names.count("replica.decode") == 1
+    root = next(s for s in spans if s["n"] == "replica.refresh")
+    lands = [s for s in spans if s["n"] == "tensor.land"]
+    assert lands and all(parent(s) == "tensor.load" and s["tr"] == root["tr"]
+                         for s in lands)
+    assert sum(s["ar"]["bytes"] for s in lands) == sum(
+        a.nbytes for a in jax.tree.leaves(np_state(state0)["params"]))
+    up, dec = (next(s for s in spans if s["n"] == n)
+               for n in ("replica.upload", "replica.decode"))
+    assert up["pa"] == dec["pa"] == 0 and up["ts"] + up["du"] <= dec["ts"]
