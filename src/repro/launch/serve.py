@@ -45,16 +45,38 @@ def publish(local: LocalServer, params: Any, *,
 def make_decode_fn(cfg: ModelConfig, tokens: int) -> Callable[[Any, np.ndarray], np.ndarray]:
     """``decode_fn(state, prompts)`` for ``SnapshotServer``: greedy-decode
     ``tokens`` tokens after each prompt's first token; returns the
-    ``(batch, tokens + 1)`` token ids on the host."""
+    ``(batch, tokens + 1)`` token ids on the host.
+
+    The state handed in is a pinned snapshot: its leaves are never written
+    in place (arena views are sealed read-only), and a new version is a new
+    tree of new leaves. So ``decode_fn`` holds one device copy, that of the
+    last tree it served, with that tree's host leaves, and uploads again only
+    when a leaf is not the same object as the one held; it drops the held
+    copy before it uploads, so the device never holds two served versions.
+    ``replica.upload`` spans every call, with ``{"bytes": n}`` put on the
+    device: the state's bytes on an upload, 0 on reuse."""
     max_len = tokens + 8
 
     @jax.jit
     def decode_one(p, cache, tok, idx):
         return M.decode_step(cfg, p, cache, tok, idx)
 
+    # (host leaves, structure, device copy) of the last tree served; the
+    # leaves are held so that no other object can take their ids
+    held = None
+
     def decode_fn(state, prompts):
-        with obs.span("replica.upload", "replica") as up:
-            p = jax.tree.map(jnp.asarray, state["params"])
+        nonlocal held
+        leaves, treedef = jax.tree.flatten(state["params"])
+        args = {"bytes": 0}
+        with obs.span("replica.upload", "replica", args=args) as up:
+            if held is None or held[1] != treedef or any(
+                    a is not b for a, b in zip(held[0], leaves)):
+                held = None  # free the old copy before the new one lands
+                held = (leaves, treedef,
+                        jax.tree.unflatten(treedef, [jnp.asarray(a) for a in leaves]))
+                args["bytes"] = sum(a.nbytes for a in leaves)
+            p = held[2]
             if up.trace_id:
                 # while the span records, end it when the weights are on the
                 # device; the first decode step waits on these same arrays
