@@ -11,6 +11,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
 FAMILIES = ("dense", "moe", "ssm", "hybrid", "audio", "vlm")
+LAYER_KINDS = ("attention", "mamba")
 
 
 @dataclass(frozen=True)
@@ -33,17 +34,33 @@ class ModelConfig:
     moe_d_ff: int = 0
     capacity_factor: float = 1.25
 
-    # --- SSM (mamba-1) ---
+    # --- SSM (mamba-1; mamba-2 when ssm_heads > 0) ---
     ssm_state: int = 0
     ssm_conv: int = 4
     d_inner: int = 0
     dt_rank: int = 0
+    ssm_heads: int = 0          # [mamba-2] heads; d_inner = ssm_heads * ssm_head_dim
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1         # [mamba-2] B/C groups shared by the heads
+    ssm_chunk: int = 256        # [mamba-2] SSD chunk length of the prefill scan
+
+    # --- layer pattern ---
+    # one kind per layer ("attention" | "mamba"), in the published order;
+    # () => every layer is the family's block
+    layer_types: Tuple[str, ...] = ()
 
     # --- attention details ---
     sliding_window: int = 0     # 0 => full attention
     qkv_bias: bool = False
     qk_norm: bool = False
+    rope: bool = True           # False => no positional encoding (NoPE)
     rope_theta: float = 10_000.0
+
+    # --- Granite's scalar multipliers (None => no operation) ---
+    embedding_multiplier: Optional[float] = None  # token embeddings times this
+    residual_multiplier: Optional[float] = None   # each block's output times this
+    attention_multiplier: Optional[float] = None  # softmax scale; None => 1/sqrt(head_dim)
+    logits_scaling: Optional[float] = None        # logits divided by this
 
     # --- modality stubs ---
     vision_prefix: int = 0      # [vlm] precomputed patch embeddings prepended
@@ -52,6 +69,19 @@ class ModelConfig:
     tie_embeddings: bool = False
     norm_eps: float = 1e-5
     source: str = ""            # provenance tag, e.g. "arXiv:2407.14679; hf"
+
+    def __post_init__(self):
+        if self.layer_types:
+            if len(self.layer_types) != self.num_layers:
+                raise ValueError(f"{self.name}: {len(self.layer_types)} layer_types "
+                                 f"for {self.num_layers} layers")
+            unknown = set(self.layer_types) - set(LAYER_KINDS)
+            if unknown:
+                raise ValueError(f"{self.name}: unknown layer types {sorted(unknown)}")
+            if "mamba" in self.layer_types and not self.ssm_heads:
+                raise ValueError(f"{self.name}: interleaved mamba layers are mamba-2")
+        if self.ssm_heads and self.ssm_heads * self.ssm_head_dim != self.d_inner:
+            raise ValueError(f"{self.name}: d_inner != ssm_heads * ssm_head_dim")
 
     # ------------------------------------------------------------------ #
     @property
@@ -79,6 +109,15 @@ class ModelConfig:
         """
         return self.family in ("ssm", "hybrid") or self.sliding_window > 0
 
+    def layer_slots(self, kind: str) -> Tuple[int, ...]:
+        """Indices of the layers of ``kind`` in an interleaved stack."""
+        return tuple(i for i, t in enumerate(self.layer_types) if t == kind)
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """[mamba-2] channels of the depthwise conv: x, B and C."""
+        return self.d_inner + 2 * self.ssm_groups * self.ssm_state
+
     @property
     def q_dim(self) -> int:
         return self.num_heads * self.head_dim
@@ -94,16 +133,25 @@ class ModelConfig:
         if not self.tie_embeddings:
             n += D * self.vocab_size  # lm head
         n += D  # final norm
-        per_layer = 0
+        attn = ssm = 0
         if self.has_attention:
-            per_layer += D * self.q_dim + 2 * D * self.kv_dim + self.q_dim * D
+            attn += D * self.q_dim + 2 * D * self.kv_dim + self.q_dim * D
             if self.qkv_bias:
-                per_layer += self.q_dim + 2 * self.kv_dim
+                attn += self.q_dim + 2 * self.kv_dim
             if self.qk_norm:
-                per_layer += 2 * self.head_dim
-        if self.has_ssm:
+                attn += 2 * self.head_dim
+        if self.ssm_heads:
+            di, h, cd = self.d_inner, self.ssm_heads, self.ssm_conv_dim
+            ssm += (
+                D * (di + cd + h)               # in_proj: z, xBC, dt
+                + cd * self.ssm_conv + cd       # conv
+                + 3 * h                         # dt_bias, A_log, D skip
+                + di                            # gated norm
+                + di * D                        # out_proj
+            )
+        elif self.has_ssm:
             di = self.d_inner
-            per_layer += (
+            ssm += (
                 D * 2 * di                      # in_proj
                 + di * self.ssm_conv + di       # conv
                 + di * (self.dt_rank + 2 * self.ssm_state)  # x_proj
@@ -111,6 +159,7 @@ class ModelConfig:
                 + di * self.ssm_state + di      # A_log, D skip
                 + di * D                        # out_proj
             )
+        per_layer = 0
         if self.is_moe:
             per_layer += D * self.num_experts  # router
             per_layer += self.num_experts * 3 * D * self.moe_d_ff
@@ -120,9 +169,12 @@ class ModelConfig:
         per_layer += D                          # ln1
         if self.is_moe or self.d_ff > 0:
             per_layer += D                      # ln2
+        if self.layer_types:
+            return (n + L * per_layer + attn * len(self.layer_slots("attention"))
+                    + ssm * len(self.layer_slots("mamba")))
         if self.family == "hybrid":
             per_layer += 2 * D                  # branch norms
-        return n + L * per_layer
+        return n + L * (per_layer + attn + ssm)
 
     def active_param_count(self) -> int:
         """Active parameters per token (MoE: routed experts only)."""
@@ -210,6 +262,7 @@ def _ensure_loaded() -> None:
         deepseek_coder_33b,
         falcon_mamba_7b,
         granite_3_2b,
+        granite_4_h_micro,
         hymba_1_5b,
         internvl2_76b,
         minitron_8b,
@@ -237,9 +290,16 @@ def reduced_config(cfg: ModelConfig, **overrides) -> ModelConfig:
         small.update(num_experts=4, num_experts_per_tok=min(2, cfg.num_experts_per_tok), moe_d_ff=32, d_ff=0)
     if cfg.has_ssm:
         small.update(d_inner=128, ssm_state=8, dt_rank=8, ssm_conv=cfg.ssm_conv)
+    if cfg.ssm_heads:
+        small.update(ssm_heads=8, ssm_head_dim=16, dt_rank=0, ssm_chunk=8)
+    if cfg.layer_types:
+        # one layer of each kind, in the order the published pattern has them
+        small.update(layer_types=tuple(dict.fromkeys(cfg.layer_types)))
     if cfg.sliding_window:
         small.update(sliding_window=32)
     if cfg.vision_prefix:
         small.update(vision_prefix=8)
     small.update(overrides)
+    if small.get("layer_types"):
+        small["num_layers"] = len(small["layer_types"])
     return dataclasses.replace(cfg, name=cfg.name + "-reduced", **small)  # type: ignore[arg-type]

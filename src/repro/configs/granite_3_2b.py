@@ -1,4 +1,5 @@
-"""Granite-3.0-2B — dense GQA, tied embeddings [hf:ibm-granite/granite-3.0-2b-base]."""
+"""Granite-3.0-2B — dense GQA, tied embeddings, Granite's four scalar
+multipliers [hf:ibm-granite/granite-3.0-2b-base]."""
 from repro.configs.base import ModelConfig, register
 
 GRANITE_3_2B = register(ModelConfig(
@@ -13,5 +14,9 @@ GRANITE_3_2B = register(ModelConfig(
     vocab_size=49_155,
     tie_embeddings=True,
     rope_theta=10_000.0,
+    embedding_multiplier=12.0,
+    residual_multiplier=0.22,
+    attention_multiplier=0.015625,
+    logits_scaling=8.0,
     source="hf:ibm-granite/granite-3.0-2b-base; hf",
 ))

@@ -1,6 +1,7 @@
 """Serving launcher: batched greedy decode from FaaSFS parameter snapshots.
 
   PYTHONPATH=src python -m repro.launch.serve --arch granite-3-2b --tokens 16
+  PYTHONPATH=src python -m repro.launch.serve --arch granite-4.0-h-micro --prompt 12
 
 The CLI serves the arch's ``reduced_config`` from an in-process backend;
 ``serve`` takes any ``ModelConfig`` and any ``LocalServer``, and
@@ -10,6 +11,7 @@ at full width over a real server.
 from __future__ import annotations
 
 import argparse
+import functools
 import time
 from typing import Any, Callable, Dict, Optional
 
@@ -44,8 +46,16 @@ def publish(local: LocalServer, params: Any, *,
 
 def make_decode_fn(cfg: ModelConfig, tokens: int) -> Callable[[Any, np.ndarray], np.ndarray]:
     """``decode_fn(state, prompts)`` for ``SnapshotServer``: greedy-decode
-    ``tokens`` tokens after each prompt's first token; returns the
-    ``(batch, tokens + 1)`` token ids on the host.
+    ``tokens`` tokens after each prompt; returns the ``(batch, prompt +
+    tokens)`` token ids on the host.
+
+    A one-token prompt is decoded from an empty cache. A longer one is
+    prefilled first, in one call (``replica.prefill``), into a cache of
+    the decode layout: K/V of the attention layers and the conv and SSM
+    state of the mamba layers, each kind for its own layers only; its last
+    position's logits give the first token and the rest are decoded
+    through that cache. ``replica.decode`` carries ``{kv_bytes,
+    ssm_bytes}``, the two kinds of decode state it runs on.
 
     The state handed in is a pinned snapshot: its leaves are never written
     in place (arena views are sealed read-only), and a new version is a new
@@ -55,11 +65,14 @@ def make_decode_fn(cfg: ModelConfig, tokens: int) -> Callable[[Any, np.ndarray],
     copy before it uploads, so the device never holds two served versions.
     ``replica.upload`` spans every call, with ``{"bytes": n}`` put on the
     device: the state's bytes on an upload, 0 on reuse."""
-    max_len = tokens + 8
 
     @jax.jit
     def decode_one(p, cache, tok, idx):
         return M.decode_step(cfg, p, cache, tok, idx)
+
+    @functools.partial(jax.jit, static_argnums=2)
+    def prefill_prompt(p, prompts, max_len):
+        return M.prefill(cfg, p, prompts, max_seq=max_len)
 
     # (host leaves, structure, device copy) of the last tree served; the
     # leaves are held so that no other object can take their ids
@@ -82,11 +95,26 @@ def make_decode_fn(cfg: ModelConfig, tokens: int) -> Callable[[Any, np.ndarray],
                 # device; the first decode step waits on these same arrays
                 # anyway, and untraced the host goes on to dispatch it
                 p = jax.block_until_ready(p)
-        with obs.span("replica.decode", "replica"):
-            cache = M.make_decode_cache(cfg, prompts.shape[0], max_len)
+        start = prompts.shape[1]
+        max_len = start - 1 + tokens + 8
+        out = [np.asarray(prompts)]
+        if start > 1:
+            with obs.span("replica.prefill", "replica", args={"tokens": start}):
+                logits, cache = prefill_prompt(p, jnp.asarray(prompts), max_len)
+                toks = jnp.argmax(logits, axis=-1)[:, None]
+                out.append(np.asarray(toks))
+            steps = range(start, start + tokens - 1)
+        else:
+            cache = None
             toks = jnp.asarray(prompts[:, :1])
-            out = [np.asarray(toks)]
-            for i in range(tokens):
+            steps = range(tokens)
+        state_bytes = {"kv_bytes": 0, "ssm_bytes": 0}
+        with obs.span("replica.decode", "replica", args=state_bytes):
+            if cache is None:
+                cache = M.make_decode_cache(cfg, prompts.shape[0], max_len)
+            for k, a in cache.items():
+                state_bytes["ssm_bytes" if k in M.KIND_CACHE["mamba"] else "kv_bytes"] += a.nbytes
+            for i in steps:
                 logits, cache = decode_one(p, cache, toks, jnp.int32(i))
                 toks = jnp.argmax(logits, axis=-1)[:, None]
                 out.append(np.asarray(toks))
@@ -95,22 +123,23 @@ def make_decode_fn(cfg: ModelConfig, tokens: int) -> Callable[[Any, np.ndarray],
     return decode_fn
 
 
-def make_prompts(cfg: ModelConfig, batch: int, seed: int) -> np.ndarray:
+def make_prompts(cfg: ModelConfig, batch: int, seed: int, length: int = 1) -> np.ndarray:
     return np.random.default_rng(seed).integers(
-        0, cfg.vocab_size, (batch, 1), dtype=np.int32)
+        0, cfg.vocab_size, (batch, length), dtype=np.int32)
 
 
 def serve(cfg: ModelConfig, local: LocalServer, *, batch: int = 4,
-          tokens: int = 16, seed: int = 0) -> np.ndarray:
+          tokens: int = 16, seed: int = 0, prompt: int = 1) -> np.ndarray:
     """Publish seeded params for ``cfg`` through ``local``, then serve one
-    batch from a snapshot replica on the same backend."""
+    batch of ``prompt``-token prompts from a snapshot replica on the same
+    backend."""
     params = jax.device_get(M.init_params(cfg, jax.random.PRNGKey(seed)))
     publish(local, params)
     srv = SnapshotServer(LocalServer(local.backend),
                          make_decode_fn(cfg, tokens), {"params": params})
     version = srv.refresh()
     t0 = time.perf_counter()
-    seqs = srv.serve(make_prompts(cfg, batch, seed))
+    seqs = srv.serve(make_prompts(cfg, batch, seed, prompt))
     dt = time.perf_counter() - t0
     dev = jax.devices()[0]
     print(f"arch={cfg.name} snapshot v{version}: decoded "
@@ -125,11 +154,13 @@ def main() -> None:
     ap.add_argument("--arch", required=True, choices=list_configs())
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--tokens", type=int, default=16)
+    ap.add_argument("--prompt", type=int, default=1,
+                    help="tokens in each prompt; more than one is prefilled")
     args = ap.parse_args()
 
     backend = BackendService(block_size=1 << 18, policy=CachePolicy.EAGER)
     seqs = serve(reduced_config(get_config(args.arch)), LocalServer(backend),
-                 batch=args.batch, tokens=args.tokens)
+                 batch=args.batch, tokens=args.tokens, prompt=args.prompt)
     for row in seqs[:2]:
         print("  ", row.tolist())
 

@@ -63,10 +63,12 @@ def attention_xla(
     q_offset: int = 0,
     kv_len: Optional[jax.Array] = None,
     q_chunk: int = 0,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """GQA attention.
 
     q: (B, Sq, H, hd); k/v: (B, Sk, KV, hd). Returns (B, Sq, H, hd).
+    ``scale`` multiplies the scores (None => 1/sqrt(hd)).
     ``q_chunk > 0`` scans over query chunks so Sq x Sk scores never
     materialize (the long-sequence / prefill path; also the oracle the
     flash kernel is validated against).
@@ -75,7 +77,8 @@ def attention_xla(
     KV = k.shape[2]
     G = H // KV
     qg = q.reshape(B, Sq, KV, G, hd)
-    scale = 1.0 / (hd ** 0.5)
+    if scale is None:
+        scale = 1.0 / (hd ** 0.5)
     Sk = k.shape[1]
     k_pos = jnp.arange(Sk)
 
@@ -109,18 +112,21 @@ def decode_attention_xla(
     cur_index: jax.Array,
     *,
     window: int = 0,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Single-token decode. q: (B, 1, H, hd); caches: (B, S, KV, hd).
 
     ``cur_index`` is the position of the query token; cache entries at
     positions <= cur_index are attended (the new token's k/v must already be
-    written). Sliding window limits attention to the last ``window`` keys.
+    written). Sliding window limits attention to the last ``window`` keys;
+    ``scale`` as in ``attention_xla``.
     """
     B, _, H, hd = q.shape
     KV = k_cache.shape[2]
     G = H // KV
     qg = q.reshape(B, 1, KV, G, hd)
-    scale = 1.0 / (hd ** 0.5)
+    if scale is None:
+        scale = 1.0 / (hd ** 0.5)
     S = k_cache.shape[1]
     k_pos = jnp.arange(S)
     ok = k_pos <= cur_index

@@ -8,10 +8,14 @@ A single table of ``ParamDef``s per architecture drives three things:
 The decoder stack is a ``lax.scan`` over layer-stacked parameters with
 rematerialization, so compile time and HLO size stay bounded for 80-layer
 configs while the roofline analyzer scales while-body costs by trip count.
+A stack of more than one kind of layer (``cfg.layer_types``: Mamba-2 mixers
+and attention) is unrolled in its published order instead, each mixer's
+parameters and decode state stacked over the layers of its kind.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -58,6 +62,8 @@ def param_defs(cfg: ModelConfig) -> List[ParamDef]:
 
     Lx = (L,)
     lax_ = ("layers",)
+    if cfg.layer_types:
+        return defs + _interleaved_defs(cfg)
 
     if cfg.has_attention:
         H, KV, hd = cfg.q_dim, cfg.kv_dim, cfg.head_dim
@@ -118,6 +124,44 @@ def param_defs(cfg: ModelConfig) -> List[ParamDef]:
             ParamDef(("layers", "mlp", "wg"), Lx + (D, F), lax_ + ("residual", "ffn")),
             ParamDef(("layers", "mlp", "wo"), Lx + (F, D), lax_ + ("ffn", "residual"), "out_normal"),
             ParamDef(("layers", "ln2"), Lx + (D,), lax_ + (None,), "ones"),
+        ]
+    return defs
+
+
+def _interleaved_defs(cfg: ModelConfig) -> List[ParamDef]:
+    """Layers of more than one kind, in ``cfg.layer_types`` order: norms and
+    the MLP stacked over every layer, each mixer stacked over the layers of
+    its kind only (``layers/attn``, ``layers/mamba``)."""
+    D, L, F = cfg.d_model, cfg.num_layers, cfg.d_ff
+    lax_ = ("layers",)
+    defs = [
+        ParamDef(("layers", "ln1"), (L, D), lax_ + (None,), "ones"),
+        ParamDef(("layers", "ln2"), (L, D), lax_ + (None,), "ones"),
+        ParamDef(("layers", "mlp", "wi"), (L, D, F), lax_ + ("residual", "ffn")),
+        ParamDef(("layers", "mlp", "wg"), (L, D, F), lax_ + ("residual", "ffn")),
+        ParamDef(("layers", "mlp", "wo"), (L, F, D), lax_ + ("ffn", "residual"), "out_normal"),
+    ]
+    La = (len(cfg.layer_slots("attention")),)
+    if La[0]:
+        H, KV = cfg.q_dim, cfg.kv_dim
+        defs += [
+            ParamDef(("layers", "attn", "wq"), La + (D, H), lax_ + ("residual", "heads")),
+            ParamDef(("layers", "attn", "wk"), La + (D, KV), lax_ + ("residual", "kv")),
+            ParamDef(("layers", "attn", "wv"), La + (D, KV), lax_ + ("residual", "kv")),
+            ParamDef(("layers", "attn", "wo"), La + (H, D), lax_ + ("heads", "residual"), "out_normal"),
+        ]
+    Lm = (len(cfg.layer_slots("mamba")),)
+    if Lm[0]:
+        Di, Hs, C, K = cfg.d_inner, cfg.ssm_heads, cfg.ssm_conv_dim, cfg.ssm_conv
+        defs += [
+            ParamDef(("layers", "mamba", "in_proj"), Lm + (D, Di + C + Hs), lax_ + ("residual", None)),
+            ParamDef(("layers", "mamba", "conv_w"), Lm + (C, K), lax_ + (None, None)),
+            ParamDef(("layers", "mamba", "conv_b"), Lm + (C,), lax_ + (None,), "zeros"),
+            ParamDef(("layers", "mamba", "dt_bias"), Lm + (Hs,), lax_ + (None,), "dt_bias"),
+            ParamDef(("layers", "mamba", "A_log"), Lm + (Hs,), lax_ + (None,), "ssm_A"),
+            ParamDef(("layers", "mamba", "D"), Lm + (Hs,), lax_ + (None,), "ones"),
+            ParamDef(("layers", "mamba", "norm"), Lm + (Di,), lax_ + ("dinner",), "ones"),
+            ParamDef(("layers", "mamba", "out_proj"), Lm + (Di, D), lax_ + ("dinner", "residual"), "out_normal"),
         ]
     return defs
 
@@ -263,10 +307,12 @@ def _attn_branch(
     if cfg.qk_norm:
         q = rms_norm_headwise(q, ap["q_norm"], cfg.norm_eps)
         k = rms_norm_headwise(k, ap["k_norm"], cfg.norm_eps)
-    q = apply_rope_cfg(cfg, q, positions)
-    k = apply_rope_cfg(cfg, k, positions)
+    if cfg.rope:
+        q = apply_rope_cfg(cfg, q, positions)
+        k = apply_rope_cfg(cfg, k, positions)
     out = attention_xla(
-        q, k, v, causal=True, window=cfg.sliding_window, q_chunk=q_chunk
+        q, k, v, causal=True, window=cfg.sliding_window, q_chunk=q_chunk,
+        scale=cfg.attention_multiplier,
     )
     out = out.reshape(B, S, cfg.q_dim)
     return jnp.einsum("bsh,hd->bsd", out, ap["wo"]), (k, v)
@@ -276,6 +322,41 @@ def apply_rope_cfg(cfg: ModelConfig, x: jax.Array, positions: jax.Array) -> jax.
     from repro.models.layers import apply_rope
 
     return apply_rope(x, positions, cfg.rope_theta)
+
+
+def _times(x: jax.Array, m: Optional[float]) -> jax.Array:
+    """``x * m``; no operation where the config has no multiplier."""
+    return x if m is None else x * m
+
+
+def _residual(cfg: ModelConfig, x: jax.Array, y: jax.Array) -> jax.Array:
+    return x + _times(y, cfg.residual_multiplier)
+
+
+def _embed(cfg: ModelConfig, params: PyTree, tokens: jax.Array, compute_dtype) -> jax.Array:
+    x = embed_tokens(params["embed"], tokens, compute_dtype)
+    return _times(x, cfg.embedding_multiplier)
+
+
+def _scale_logits(cfg: ModelConfig, logits: jax.Array) -> jax.Array:
+    return logits if cfg.logits_scaling is None else logits / cfg.logits_scaling
+
+
+def _mamba2_dims(cfg: ModelConfig) -> Dict[str, Any]:
+    return dict(heads=cfg.ssm_heads, head_dim=cfg.ssm_head_dim,
+                state=cfg.ssm_state, groups=cfg.ssm_groups, eps=cfg.norm_eps)
+
+
+def _layer_params(cfg: ModelConfig, layers: PyTree, i: int) -> Dict:
+    """Layer ``i`` of an interleaved stack: its norms and MLP, and the mixer
+    of its kind at that kind's own index."""
+    kind = cfg.layer_types[i]
+    slot = cfg.layer_types[:i].count(kind)
+    mixer = "attn" if kind == "attention" else "mamba"
+    lp = {k: layers[k][i] for k in ("ln1", "ln2")}
+    lp["mlp"] = jax.tree.map(lambda a: a[i], layers["mlp"])
+    lp[mixer] = jax.tree.map(lambda a: a[slot], layers[mixer])
+    return lp
 
 
 def _ffn_branch(cfg: ModelConfig, lp: Dict, x: jax.Array, shardings: ActSharding):
@@ -321,11 +402,17 @@ def block_fwd(
     *,
     q_chunk: int = 0,
     collect_cache: bool = False,
+    kind: Optional[str] = None,
 ):
-    """One decoder block. Returns (x, aux_loss, cache_kv | None)."""
+    """One decoder block: the family's, or the ``kind`` of an interleaved
+    stack's layer. Returns (x, aux_loss, cache | None): (k, v) of an
+    attention layer, (conv, ssm) state of a mamba-2 layer."""
     h = rms_norm(x, lp["ln1"], cfg.norm_eps)
     cache = None
-    if cfg.family == "hybrid":
+    if kind == "mamba":
+        mix, cache = ssm_mod.mamba2_block(
+            h, lp["mamba"], chunk=cfg.ssm_chunk, **_mamba2_dims(cfg))
+    elif kind is None and cfg.family == "hybrid":
         attn_out, cache = _attn_branch(cfg, lp, h, positions, q_chunk=q_chunk)
         ssm_out = ssm_mod.mamba_block(
             h, lp["ssm"], dt_rank=cfg.dt_rank, ssm_state=cfg.ssm_state
@@ -334,19 +421,19 @@ def block_fwd(
             rms_norm(attn_out, lp["attn_branch_norm"], cfg.norm_eps)
             + rms_norm(ssm_out, lp["ssm_branch_norm"], cfg.norm_eps)
         )
-    elif cfg.family == "ssm":
+    elif kind is None and cfg.family == "ssm":
         mix = ssm_mod.mamba_block(
             h, lp["ssm"], dt_rank=cfg.dt_rank, ssm_state=cfg.ssm_state
         )
     else:
         mix, cache = _attn_branch(cfg, lp, h, positions, q_chunk=q_chunk)
-    x = shardings.res(x + mix)
+    x = shardings.res(_residual(cfg, x, mix))
 
     aux = jnp.zeros((), jnp.float32)
     if cfg.is_moe or cfg.d_ff > 0:
         h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
         y, aux = _ffn_branch(cfg, lp, h2, shardings)
-        x = shardings.res(x + y)
+        x = shardings.res(_residual(cfg, x, y))
     if not collect_cache:
         cache = None
     return x, aux, cache
@@ -370,11 +457,13 @@ def forward_hidden(
 ):
     """Embed -> scan(blocks) -> final norm.
 
-    Returns (hidden (B, S, D), aux_loss, cache (L,B,S,KV,hd)x2 | None).
-    ``remat_policy``: None (save nothing, recompute all) | "dots" (save dot
-    outputs — trades activation memory for recompute traffic; §Perf knob).
+    Returns (hidden (B, S, D), aux_loss, cache | None): (k, v) each
+    (L,B,S,KV,hd); for an interleaved stack, ``{kind: per-layer caches
+    stacked over that kind's layers}``. ``remat_policy``: None (save
+    nothing, recompute all) | "dots" (save dot outputs — trades activation
+    memory for recompute traffic; §Perf knob).
     """
-    x = embed_tokens(params["embed"], tokens, compute_dtype)
+    x = _embed(cfg, params, tokens, compute_dtype)
     if cfg.vision_prefix and pixel_embeds is not None:
         x = jnp.concatenate([pixel_embeds.astype(compute_dtype), x], axis=1)
     B, S, _ = x.shape
@@ -383,22 +472,35 @@ def forward_hidden(
 
     layers = jax.tree.map(lambda p: p.astype(compute_dtype), params["layers"])
 
-    def body_inner(x, lp):
+    def body_inner(x, lp, kind=None):
         x, aux, cache = block_fwd(
             cfg, x, lp, positions, shardings,
-            q_chunk=q_chunk, collect_cache=collect_cache,
+            q_chunk=q_chunk, collect_cache=collect_cache, kind=kind,
         )
         return x, aux, cache
 
-    if remat and remat_policy == "dots":
-        wrapped = jax.checkpoint(
-            body_inner,
-            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-        )
-    elif remat:
-        wrapped = jax.checkpoint(body_inner)
-    else:
-        wrapped = body_inner
+    def remat_wrap(fn):
+        if remat and remat_policy == "dots":
+            return jax.checkpoint(
+                fn, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+        return jax.checkpoint(fn) if remat else fn
+
+    if cfg.layer_types:
+        # unrolled in the published order; each kind's caches stacked
+        aux_sum = jnp.zeros((), jnp.float32)
+        per_kind: Dict[str, List] = {}
+        for i, kind in enumerate(cfg.layer_types):
+            layer = remat_wrap(functools.partial(body_inner, kind=kind))
+            x, aux, cache = layer(x, _layer_params(cfg, layers, i))
+            aux_sum = aux_sum + aux
+            if collect_cache:
+                per_kind.setdefault(kind, []).append(cache)
+        caches = {k: jax.tree.map(lambda *a: jnp.stack(a), *c)
+                  for k, c in per_kind.items()} if collect_cache else None
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        return x, aux_sum, caches
+
+    wrapped = remat_wrap(body_inner)
 
     def body(carry, lp):
         x, aux_sum = carry
@@ -440,6 +542,8 @@ def loss_fn(
         remat_policy=remat_policy,
     )
     head = lm_head_weight(cfg, params).astype(compute_dtype)
+    if cfg.logits_scaling is not None:
+        hidden = hidden * (1.0 / cfg.logits_scaling)
     labels = batch["labels"]
     mask = batch["mask"].astype(jnp.float32)
     if cfg.vision_prefix:
@@ -466,8 +570,18 @@ def prefill(
     shardings: ActSharding = NO_SHARDING,
     compute_dtype=jnp.bfloat16,
     q_chunk: int = 1024,
+    max_seq: Optional[int] = None,
 ):
-    """Returns (last-position logits (B, V), cache)."""
+    """Returns (last-position logits (B, V), cache).
+
+    The cache holds K/V of the attention layers, (L, B, S, KV, hd) each;
+    an interleaved stack's also holds the conv and SSM state of its mamba
+    layers, each kind stacked over its own layers. With ``max_seq`` it is
+    ``make_decode_cache``'s layout, K/V padded to ``max_seq`` positions, so
+    that ``decode_step`` continues from it at position S.
+    """
+    if max_seq is not None and cfg.has_ssm and not cfg.layer_types:
+        raise NotImplementedError(f"{cfg.name}: prefill keeps no mamba-1 state")
     hidden, _, caches = forward_hidden(
         cfg,
         params,
@@ -476,24 +590,42 @@ def prefill(
         shardings=shardings,
         compute_dtype=compute_dtype,
         q_chunk=q_chunk,
-        collect_cache=cfg.has_attention,
+        collect_cache=cfg.has_attention or bool(cfg.layer_types),
         remat=False,
     )
     head = lm_head_weight(cfg, params).astype(compute_dtype)
     logits = jnp.einsum("bd,dv->bv", hidden[:, -1], head).astype(jnp.float32)
-    cache = None
-    if cfg.has_attention and caches is not None:
-        k, v = caches
+    logits = _scale_logits(cfg, logits)
+    if caches is None:
+        return logits, None
+    cache: Dict[str, Any] = {}
+    if cfg.layer_types:
+        if "attention" in caches:
+            cache["k"], cache["v"] = caches["attention"]
+        if "mamba" in caches:
+            cache["conv"], cache["ssm"] = caches["mamba"]
+    else:
+        cache["k"], cache["v"] = caches
+    if "k" in cache:
+        k, v = cache["k"], cache["v"]
+        if max_seq is not None:
+            pad = [(0, 0)] * k.ndim
+            pad[2] = (0, max_seq - k.shape[2])
+            k, v = jnp.pad(k, pad), jnp.pad(v, pad)
         if shardings.kv_cache is not None:
             k = constrain(k, shardings.kv_cache)
             v = constrain(v, shardings.kv_cache)
-        cache = {"k": k, "v": v}
+        cache["k"], cache["v"] = k, v
     return logits, cache
 
 
 # --------------------------------------------------------------------------- #
 # Decode
 # --------------------------------------------------------------------------- #
+#: decode-cache entries of each kind of layer
+KIND_CACHE = {"attention": ("k", "v", "k_scale", "v_scale"), "mamba": ("conv", "ssm")}
+
+
 def make_decode_cache(
     cfg: ModelConfig, batch: int, max_seq: int, dtype=jnp.bfloat16
 ) -> PyTree:
@@ -502,18 +634,29 @@ def make_decode_cache(
     ``dtype=jnp.int8`` stores quantized K/V with per-(position, kv-head)
     fp32 scales — halves the dominant decode-HBM term (§Perf); dequant
     happens per attention call (fused into the kernel's VMEM tiles on TPU).
+    An interleaved stack holds K/V for its attention layers only and conv
+    and SSM state for its mamba layers only.
     """
     cache: Dict[str, Any] = {}
     L = cfg.num_layers
-    if cfg.has_attention:
-        shape = (L, batch, max_seq, cfg.num_kv_heads, cfg.head_dim)
+    La = len(cfg.layer_slots("attention")) if cfg.layer_types else L
+    if cfg.has_attention and La:
+        shape = (La, batch, max_seq, cfg.num_kv_heads, cfg.head_dim)
         cache["k"] = jnp.zeros(shape, dtype)
         cache["v"] = jnp.zeros(shape, dtype)
         if dtype == jnp.int8:
-            sshape = (L, batch, max_seq, cfg.num_kv_heads)
+            sshape = (La, batch, max_seq, cfg.num_kv_heads)
             cache["k_scale"] = jnp.ones(sshape, jnp.float32)
             cache["v_scale"] = jnp.ones(sshape, jnp.float32)
-    if cfg.has_ssm:
+    if cfg.layer_types:
+        Lm = len(cfg.layer_slots("mamba"))
+        if Lm:
+            cache["conv"] = jnp.zeros(
+                (Lm, batch, cfg.ssm_conv - 1, cfg.ssm_conv_dim),
+                jnp.bfloat16 if dtype == jnp.int8 else dtype)
+            cache["ssm"] = jnp.zeros(
+                (Lm, batch, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32)
+    elif cfg.has_ssm:
         cache["conv"] = jnp.zeros((L, batch, cfg.ssm_conv - 1, cfg.d_inner), jnp.bfloat16)
         cache["ssm"] = jnp.zeros((L, batch, cfg.d_inner, cfg.ssm_state), jnp.float32)
     return cache
@@ -532,8 +675,10 @@ def _decode_block(
     cl: Dict,
     cur_index: jax.Array,
     shardings: ActSharding,
+    kind: Optional[str] = None,
 ):
-    """x: (B,1,D); cl: per-layer cache slices. Returns (x, new_cl)."""
+    """x: (B,1,D); cl: per-layer cache slices; ``kind`` as in ``block_fwd``.
+    Returns (x, new_cl)."""
     B = x.shape[0]
     h = rms_norm(x, lp["ln1"], cfg.norm_eps)
     new_cl: Dict[str, jax.Array] = {}
@@ -554,8 +699,9 @@ def _decode_block(
         if cfg.qk_norm:
             q = rms_norm_headwise(q, ap["q_norm"], cfg.norm_eps)
             k = rms_norm_headwise(k, ap["k_norm"], cfg.norm_eps)
-        q = apply_rope_cfg(cfg, q, pos)
-        k = apply_rope_cfg(cfg, k, pos)
+        if cfg.rope:
+            q = apply_rope_cfg(cfg, q, pos)
+            k = apply_rope_cfg(cfg, k, pos)
         new_scales = {}
         if quantized:
             # per-(position, kv-head) int8 quantization of the new K/V
@@ -582,7 +728,8 @@ def _decode_block(
         else:
             k_use, v_use = kc, vc
         out = decode_attention_xla(q.astype(k_use.dtype), k_use, v_use,
-                                   cur_index, window=cfg.sliding_window)
+                                   cur_index, window=cfg.sliding_window,
+                                   scale=cfg.attention_multiplier)
         out = out.reshape(B, 1, cfg.q_dim)
         return jnp.einsum("bsh,hd->bsd", out.astype(h.dtype), ap["wo"]), kc, vc, new_scales
 
@@ -592,7 +739,11 @@ def _decode_block(
             dt_rank=cfg.dt_rank, ssm_state=cfg.ssm_state,
         )
 
-    if cfg.family == "hybrid":
+    if kind == "mamba":
+        mix, conv_s, ssm_s = ssm_mod.mamba2_decode_step(
+            h, lp["mamba"], cl["conv"], cl["ssm"], **_mamba2_dims(cfg))
+        new_cl.update(conv=conv_s, ssm=ssm_s)
+    elif kind is None and cfg.family == "hybrid":
         attn_out, kc, vc, scales = attn(h)
         ssm_out, conv_s, ssm_s = ssm_step(h)
         new_cl.update(k=kc, v=vc, conv=conv_s, ssm=ssm_s, **scales)
@@ -600,18 +751,18 @@ def _decode_block(
             rms_norm(attn_out, lp["attn_branch_norm"], cfg.norm_eps)
             + rms_norm(ssm_out, lp["ssm_branch_norm"], cfg.norm_eps)
         )
-    elif cfg.family == "ssm":
+    elif kind is None and cfg.family == "ssm":
         mix, conv_s, ssm_s = ssm_step(h)
         new_cl.update(conv=conv_s, ssm=ssm_s)
     else:
         mix, kc, vc, scales = attn(h)
         new_cl.update(k=kc, v=vc, **scales)
-    x = shardings.dres(x + mix)
+    x = shardings.dres(_residual(cfg, x, mix))
 
     if cfg.is_moe or cfg.d_ff > 0:
         h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
         y, _ = _ffn_branch(cfg, lp, h2, shardings)
-        x = shardings.dres(x + y)
+        x = shardings.dres(_residual(cfg, x, y))
     return x, new_cl
 
 
@@ -626,18 +777,32 @@ def decode_step(
     compute_dtype=jnp.bfloat16,
 ):
     """One token for every sequence. tokens: (B, 1) -> (logits (B,V), cache)."""
-    x = embed_tokens(params["embed"], tokens, compute_dtype)
+    x = _embed(cfg, params, tokens, compute_dtype)
     x = shardings.dres(x)
     layers = jax.tree.map(lambda p: p.astype(compute_dtype), params["layers"])
-    cache_f = jax.tree.map(lambda c: c, cache)
 
-    def body(x, inp):
-        lp, cl = inp
-        x, new_cl = _decode_block(cfg, x, lp, cl, cur_index, shardings)
-        return x, new_cl
+    if cfg.layer_types:
+        # unrolled in the published order; each layer reads and writes
+        # only its own kind's cache, at that kind's index
+        new: Dict[str, List] = {k: [] for k in cache}
+        for i, kind in enumerate(cfg.layer_types):
+            slot = cfg.layer_types[:i].count(kind)
+            cl = {k: cache[k][slot] for k in KIND_CACHE[kind] if k in cache}
+            x, new_cl = _decode_block(cfg, x, _layer_params(cfg, layers, i), cl,
+                                      cur_index, shardings, kind=kind)
+            for k, v in new_cl.items():
+                new[k].append(v)
+        new_cache = {k: jnp.stack(v) for k, v in new.items()}
+    else:
+        cache_f = jax.tree.map(lambda c: c, cache)
 
-    x, new_cache = jax.lax.scan(body, x, (layers, cache_f))
+        def body(x, inp):
+            lp, cl = inp
+            x, new_cl = _decode_block(cfg, x, lp, cl, cur_index, shardings)
+            return x, new_cl
+
+        x, new_cache = jax.lax.scan(body, x, (layers, cache_f))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = lm_head_weight(cfg, params).astype(compute_dtype)
     logits = jnp.einsum("bsd,dv->bsv", x, head)[:, 0].astype(jnp.float32)
-    return logits, new_cache
+    return _scale_logits(cfg, logits), new_cache

@@ -233,6 +233,8 @@ def cache_partition_specs(plan: CellPlan) -> PyTree:
     if cfg.has_ssm:
         specs["conv"] = P(None, plan.batch_axes, None, "model")
         specs["ssm"] = P(None, plan.batch_axes, "model", None)
+        if cfg.ssm_heads:  # mamba-2: (L, B, heads, head_dim, state)
+            specs["ssm"] = P(None, plan.batch_axes, "model", None, None)
     return specs
 
 

@@ -1,10 +1,16 @@
-"""Mamba-1 selective SSM block (xla reference path).
+"""Selective SSM mixers (xla path): Mamba-1 and Mamba-2 (SSD).
 
-Training/prefill uses a chunked scan: a sequential ``lax.scan`` over sequence
-chunks carrying the (B, D_inner, N) state, with an associative scan inside
-each chunk — this bounds the materialized (B, chunk, D_inner, N) tensors
-(the same chunking scheme the Pallas ``ssm_scan`` kernel implements with
-VMEM tiles). Decode is the O(1) single-step recurrence.
+Mamba-1 training/prefill uses a chunked scan: a sequential ``lax.scan`` over
+sequence chunks carrying the (B, D_inner, N) state, with an associative scan
+inside each chunk — this bounds the materialized (B, chunk, D_inner, N)
+tensors (the same chunking scheme the Pallas ``ssm_scan`` kernel implements
+with VMEM tiles). Decode is the O(1) single-step recurrence.
+
+Mamba-2 (Dao & Gu, "Transformers are SSMs", arXiv:2405.21060) has one scalar
+decay per head: h <- exp(dt*A) h + dt x (x) B, y = C.h + D x. Its prefill is
+the chunked SSD form (a quadratic, attention-like product inside each chunk,
+a recurrence over chunk states between them); its decode is the same
+one-step recurrence.
 """
 from __future__ import annotations
 
@@ -170,3 +176,169 @@ def mamba_decode_step(
     y = y * (z * jax.nn.sigmoid(z.astype(jnp.float32)).astype(z.dtype))
     out = jnp.einsum("bse,ed->bsd", y, p["out_proj"])
     return out, window[:, 1:], h
+
+
+# --------------------------------------------------------------------------- #
+# Mamba-2 (SSD)
+# --------------------------------------------------------------------------- #
+def _silu(x: jax.Array) -> jax.Array:
+    return x * jax.nn.sigmoid(x.astype(jnp.float32)).astype(x.dtype)
+
+
+def gated_rms_norm(y: jax.Array, z: jax.Array, w: jax.Array, groups: int,
+                   eps: float) -> jax.Array:
+    """Mamba-2's output norm: ``y * silu(z)``, RMS-normalised over each of
+    ``groups`` equal slices of the channels in float32, times ``w``; in
+    ``z``'s dtype. y/z: (B, S, Di); w: (Di,)."""
+    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    shape = g.shape
+    g = g.reshape(*shape[:-1], groups, shape[-1] // groups)
+    g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
+    return g.reshape(shape).astype(z.dtype) * w.astype(z.dtype)
+
+
+def ssd_scan(
+    x: jax.Array,
+    dt: jax.Array,
+    A: jax.Array,
+    B_: jax.Array,
+    C: jax.Array,
+    *,
+    chunk: int,
+    h0: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, jax.Array]:
+    """Chunked SSD: the Mamba-2 recurrence over a whole sequence.
+
+    x: (B, S, H, P); dt: (B, S, H) (after softplus); A: (H,) negative;
+    B_/C: (B, S, G, N), each group shared by H // G heads; h0: (B, H, P, N).
+    Returns (y (B, S, H, P) float32 without the D skip, h_last (B, H, P, N)
+    float32). A ``lax.scan`` over chunks of ``chunk`` positions carries the
+    state: inside a chunk the output is ``(C B^T o L) X`` with the decay
+    matrix ``L[l, s] = exp(sum(dt A) over (s, l])``, plus the carried
+    state's contribution; a sequence that is not a whole number of chunks
+    is padded with dt = 0, which leaves the state unchanged.
+    """
+    Bsz, S, H, P = x.shape
+    G, N = B_.shape[2], B_.shape[3]
+    Hg = H // G
+    c = min(chunk, S)
+    pad = (-S) % c
+    if pad:
+        widths = lambda t: ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2)  # noqa: E731
+        x, dt, B_, C = (jnp.pad(t, widths(t)) for t in (x, dt, B_, C))
+    n = (S + pad) // c
+    f32 = jnp.float32
+    if h0 is None:
+        h0 = jnp.zeros((Bsz, H, P, N), f32)
+
+    def split(t):  # (B, n*c, ...) -> (n, B, c, ...)
+        return t.reshape(Bsz, n, c, *t.shape[2:]).swapaxes(0, 1)
+
+    xs = split((x.astype(f32) * dt[..., None]).reshape(Bsz, S + pad, G, Hg, P))
+    a = split((dt * A[None, None, :]).reshape(Bsz, S + pad, G, Hg))
+    Bs, Cs = split(B_.astype(f32)), split(C.astype(f32))
+    tril = jnp.tril(jnp.ones((c, c), bool))
+
+    @jax.checkpoint
+    def chunk_fn(h, inp):
+        xc, ac, Bc, Cc = inp                       # (B,c,G,Hg,P), (B,c,G,Hg), (B,c,G,N)
+        cs = jnp.cumsum(ac, axis=1)                # (B,c,G,Hg)
+        seg = cs[:, :, None] - cs[:, None, :]      # (B,l,s,G,Hg)
+        decay = jnp.exp(jnp.where(tril[None, :, :, None, None], seg, -jnp.inf))
+        cb = jnp.einsum("blgn,bsgn->blsg", Cc, Bc)
+        y = jnp.einsum("blsg,blsgh,bsghp->blghp", cb, decay, xc)
+        y = y + jnp.einsum("blgn,bghpn->blghp", Cc, h) * jnp.exp(cs)[..., None]
+        last = cs[:, -1]                           # (B,G,Hg)
+        into = jnp.exp(last[:, None] - cs)         # (B,c,G,Hg)
+        h = (h * jnp.exp(last)[..., None, None]
+             + jnp.einsum("bsgn,bsgh,bsghp->bghpn", Bc, into, xc))
+        return h, y
+
+    h, ys = jax.lax.scan(chunk_fn, h0.reshape(Bsz, G, Hg, P, N), (xs, a, Bs, Cs))
+    y = ys.swapaxes(0, 1).reshape(Bsz, S + pad, H, P)[:, :S]
+    return y, h.reshape(Bsz, H, P, N)
+
+
+def _mamba2_in(x: jax.Array, p: Dict[str, jax.Array], d_inner: int, heads: int):
+    """in_proj, split into the gate z, the conv's input xBC and raw dt."""
+    zxbcdt = jnp.einsum("bsd,de->bse", x, p["in_proj"])
+    z = zxbcdt[..., :d_inner]
+    xbc = zxbcdt[..., d_inner:-heads]
+    dt = zxbcdt[..., -heads:]
+    return z, xbc, dt
+
+
+def _mamba2_out(y, xs, z, p, *, groups: int, eps: float):
+    """The D skip, the gated norm and out_proj. y/xs: (B, S, Di)."""
+    y = y + xs.astype(jnp.float32) * jnp.repeat(
+        p["D"].astype(jnp.float32), xs.shape[-1] // p["D"].shape[0])
+    y = gated_rms_norm(y, z, p["norm"], groups, eps)
+    return jnp.einsum("bse,ed->bsd", y, p["out_proj"])
+
+
+def mamba2_block(
+    x: jax.Array,
+    p: Dict[str, jax.Array],
+    *,
+    heads: int,
+    head_dim: int,
+    state: int,
+    groups: int,
+    chunk: int,
+    eps: float,
+) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
+    """Mamba-2 mixer over a sequence. x: (B, S, D) -> (y (B, S, D),
+    (conv_state (B, K-1, conv_dim), ssm_state (B, H, P, N) float32)): the
+    decode state after the last position."""
+    Bsz, S, _ = x.shape
+    Di, GN = heads * head_dim, groups * state
+    z, xbc, dt = _mamba2_in(x, p, Di, heads)
+    K = p["conv_w"].shape[1]
+    conv_state = jnp.pad(xbc, ((0, 0), (max(0, K - 1 - S), 0), (0, 0)))[:, -(K - 1):]
+    xbc = _silu(causal_conv1d(xbc, p["conv_w"], p["conv_b"]))
+    xs, B_, C = xbc[..., :Di], xbc[..., Di:Di + GN], xbc[..., Di + GN:]
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
+    A = -jnp.exp(p["A_log"].astype(jnp.float32))
+    y, h = ssd_scan(
+        xs.reshape(Bsz, S, heads, head_dim), dt, A,
+        B_.reshape(Bsz, S, groups, state), C.reshape(Bsz, S, groups, state),
+        chunk=chunk)
+    out = _mamba2_out(y.reshape(Bsz, S, Di), xs, z, p, groups=groups, eps=eps)
+    return out, (conv_state, h)
+
+
+def mamba2_decode_step(
+    x: jax.Array,
+    p: Dict[str, jax.Array],
+    conv_state: jax.Array,
+    ssm_state_v: jax.Array,
+    *,
+    heads: int,
+    head_dim: int,
+    state: int,
+    groups: int,
+    eps: float,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """x: (B, 1, D); conv_state: (B, K-1, conv_dim); ssm_state_v: (B, H, P, N).
+
+    Returns (y (B,1,D), new_conv_state, new_ssm_state).
+    """
+    Bsz = x.shape[0]
+    Di, GN, Hg = heads * head_dim, groups * state, heads // groups
+    z, xbc, dt = _mamba2_in(x, p, Di, heads)
+    window = jnp.concatenate([conv_state, xbc], axis=1)     # (B,K,C)
+    conv = jnp.einsum("bkc,ck->bc", window, p["conv_w"]) + p["conv_b"]
+    conv = _silu(conv)                                      # (B,C)
+    xs, B_, C = conv[:, :Di], conv[:, Di:Di + GN], conv[:, Di + GN:]
+    dt = jax.nn.softplus(dt[:, 0].astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
+    A = -jnp.exp(p["A_log"].astype(jnp.float32))
+    dA = jnp.exp(dt * A[None]).reshape(Bsz, groups, Hg)     # (B,G,Hg)
+    xh = (xs.astype(jnp.float32).reshape(Bsz, heads, head_dim)
+          * dt[..., None]).reshape(Bsz, groups, Hg, head_dim)
+    Bg = B_.astype(jnp.float32).reshape(Bsz, groups, state)
+    Cg = C.astype(jnp.float32).reshape(Bsz, groups, state)
+    h = ssm_state_v.reshape(Bsz, groups, Hg, head_dim, state)
+    h = h * dA[..., None, None] + xh[..., None] * Bg[:, :, None, None, :]
+    y = jnp.einsum("bghpn,bgn->bghp", h, Cg).reshape(Bsz, 1, Di)
+    out = _mamba2_out(y, xs[:, None], z, p, groups=groups, eps=eps)
+    return out, window[:, 1:], h.reshape(Bsz, heads, head_dim, state)
