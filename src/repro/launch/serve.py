@@ -55,7 +55,17 @@ def make_decode_fn(cfg: ModelConfig, tokens: int) -> Callable[[Any, np.ndarray],
     state of the mamba layers, each kind for its own layers only; its last
     position's logits give the first token and the rest are decoded
     through that cache. ``replica.decode`` carries ``{kv_bytes,
-    ssm_bytes}``, the two kinds of decode state it runs on.
+    ssm_bytes}``, the two kinds of decode state it runs on, and
+    ``host_reads``, the device-to-host reads made inside it.
+
+    The token loop dispatches step ``i + 1`` right after step ``i``, with
+    no host read between them: each jitted step (``decode_one``, one
+    decode step and its argmax) takes the last step's ids as a device
+    array, so the device runs the steps back to back while the host queues
+    them. The request's ids come to the host in one read at the end of
+    ``replica.decode`` (``host_reads`` 1), so the span still ends when the
+    whole answer is on the host. ``replica.prefill`` ends on its own read
+    of the first token, so that it holds the prefill's device time.
 
     The state handed in is a pinned snapshot: its leaves are never written
     in place (arena views are sealed read-only), and a new version is a new
@@ -68,7 +78,8 @@ def make_decode_fn(cfg: ModelConfig, tokens: int) -> Callable[[Any, np.ndarray],
 
     @jax.jit
     def decode_one(p, cache, tok, idx):
-        return M.decode_step(cfg, p, cache, tok, idx)
+        logits, cache = M.decode_step(cfg, p, cache, tok, idx)
+        return jnp.argmax(logits, axis=-1)[:, None], cache
 
     @functools.partial(jax.jit, static_argnums=2)
     def prefill_prompt(p, prompts, max_len):
@@ -108,16 +119,19 @@ def make_decode_fn(cfg: ModelConfig, tokens: int) -> Callable[[Any, np.ndarray],
             cache = None
             toks = jnp.asarray(prompts[:, :1])
             steps = range(tokens)
-        state_bytes = {"kv_bytes": 0, "ssm_bytes": 0}
-        with obs.span("replica.decode", "replica", args=state_bytes):
+        decode_args = {"kv_bytes": 0, "ssm_bytes": 0, "host_reads": 0}
+        with obs.span("replica.decode", "replica", args=decode_args):
             if cache is None:
                 cache = M.make_decode_cache(cfg, prompts.shape[0], max_len)
             for k, a in cache.items():
-                state_bytes["ssm_bytes" if k in M.KIND_CACHE["mamba"] else "kv_bytes"] += a.nbytes
+                decode_args["ssm_bytes" if k in M.KIND_CACHE["mamba"] else "kv_bytes"] += a.nbytes
+            ids = []
             for i in steps:
-                logits, cache = decode_one(p, cache, toks, jnp.int32(i))
-                toks = jnp.argmax(logits, axis=-1)[:, None]
-                out.append(np.asarray(toks))
+                toks, cache = decode_one(p, cache, toks, np.int32(i))
+                ids.append(toks)
+            if ids:
+                out.append(jax.device_get(jnp.concatenate(ids, axis=1)))
+                decode_args["host_reads"] += 1
             return np.concatenate(out, axis=1)
 
     return decode_fn
