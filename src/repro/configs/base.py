@@ -1,7 +1,7 @@
 """Architecture config system.
 
 Every assigned architecture is a frozen ``ModelConfig``; every assigned
-input-shape cell is a ``ShapeCell``. The dry-run, smoke tests, benchmarks and
+input-shape cell is a ``ShapeCell``. The smoke tests, the benchmark and the
 launchers all key off this registry (``--arch <id>``).
 """
 from __future__ import annotations
@@ -176,17 +176,6 @@ class ModelConfig:
             per_layer += 2 * D                  # branch norms
         return n + L * (per_layer + attn + ssm)
 
-    def active_param_count(self) -> int:
-        """Active parameters per token (MoE: routed experts only)."""
-        if not self.is_moe:
-            return self.param_count()
-        full = self.param_count()
-        moe_all = self.num_layers * self.num_experts * 3 * self.d_model * self.moe_d_ff
-        moe_active = (
-            self.num_layers * self.num_experts_per_tok * 3 * self.d_model * self.moe_d_ff
-        )
-        return full - moe_all + moe_active
-
 
 @dataclass(frozen=True)
 class ShapeCell:
@@ -216,7 +205,7 @@ def cell_applicable(cfg: ModelConfig, shape: ShapeCell) -> Tuple[bool, str]:
     if shape.name == "long_500k" and not cfg.sub_quadratic:
         return False, (
             "long_500k requires sub-quadratic attention; "
-            f"{cfg.name} is pure full-attention (see DESIGN.md)"
+            f"{cfg.name} is pure full-attention"
         )
     return True, ""
 

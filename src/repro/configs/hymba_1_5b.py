@@ -1,6 +1,6 @@
 """Hymba-1.5B — hybrid: parallel attention + mamba heads [arXiv:2411.13676; hf].
 
-Simplification recorded in DESIGN.md: all layers use the hybrid block with
+Simplification: all layers use the hybrid block with
 sliding-window attention (the published model keeps a few global-attention
 layers); head fusion is the mean of the attention and SSM branches after
 per-branch normalization.

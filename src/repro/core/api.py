@@ -14,9 +14,9 @@ and the scalar forms the original API shipped with (``fetch_block``,
 ``fetch_meta``, ``lookup``, ``sync_file``) are concrete shims over the
 batch core defined once, here. A backend therefore implements ONE
 surface; clients may call either form, and a batch is one logical round
-trip on every transport (`LatencyInjector` charges it as one, the wire
-ships it as one frame, `ShardedBackend` fans it out and merges
-server-side exactly like ``begin``).
+trip on every transport (the wire ships it as one frame,
+`ShardedBackend` fans it out and merges server-side exactly like
+``begin``).
 
 **Futures.** ``submit(op, *args) -> BackendFuture`` is the pipelining
 hook: callers get a completion handle instead of blocking the thread.
@@ -31,20 +31,15 @@ timestamps themselves: the monolithic backend uses scalar timestamps,
 the sharded backend a per-shard vector, and client code works unchanged
 over both.
 
-Transport concerns live in wrappers, not in the backend:
-``LatencyInjector`` charges one simulated network round trip per
-client-visible call — batch or scalar — replacing the old ad-hoc
-``rpc_latency_s`` sleeps inside ``BackendService``. The real networked
-transport is ``repro.core.remote.RemoteBackend`` — the same calls
-serialized over a socket to ``repro.core.server.BackendServer`` (wire
-format in ``repro.core.wire``, durable commit log in ``repro.core.wal``;
-see docs/transport.md). ``bench_remote`` calibrates the injector's
-simulated RTT against the real thing.
+Transport concerns live outside the backend: the networked transport
+is ``repro.core.remote.RemoteBackend`` — the same calls serialized over
+a socket to ``repro.core.server.BackendServer`` (wire format in
+``repro.core.wire``, durable commit log in ``repro.core.wal``; see
+docs/transport.md).
 """
 from __future__ import annotations
 
 import threading
-import time
 from abc import ABC, abstractmethod
 from dataclasses import dataclass, field
 from typing import TYPE_CHECKING, Any, Dict, List, Optional, Set, Tuple
@@ -320,118 +315,3 @@ class BackendAPI(ABC):
             fut.set_exception(e)
         return fut
 
-
-#: calls that cost one network round trip in the paper's EC2 deployment;
-#: lookup/fetch_meta/listdir piggyback on other messages there. A batch
-#: call is ONE round trip no matter how many items it carries.
-DEFAULT_CHARGED_CALLS = (
-    "begin",
-    "sync_file",
-    "sync_files",
-    "fetch_block",
-    "fetch_blocks",
-    "commit",
-)
-
-
-class LatencyInjector(BackendAPI):
-    """Transport wrapper charging a simulated RTT per client-visible call.
-
-    Wrap any ``BackendAPI`` (monolithic or sharded) to model a networked
-    deployment::
-
-        be = LatencyInjector(BackendService(...), rpc_latency_s=100e-6)
-
-    Batch calls are charged as ONE round trip — the whole point of the
-    batch-first surface — so mono / sharded / remote backends stay
-    comparable under the simulation.
-    """
-
-    def __init__(
-        self,
-        inner: BackendAPI,
-        rpc_latency_s: float,
-        charged_calls: Tuple[str, ...] = DEFAULT_CHARGED_CALLS,
-    ):
-        self.inner = inner
-        self.rpc_latency_s = rpc_latency_s
-        self.charged_calls = frozenset(charged_calls)
-
-    def _rpc(self, call: str) -> None:
-        if self.rpc_latency_s and call in self.charged_calls:
-            time.sleep(self.rpc_latency_s)
-
-    # -------------------------- delegation ---------------------------- #
-    @property
-    def block_size(self) -> int:
-        return self.inner.block_size
-
-    @property
-    def policy(self) -> CachePolicy:
-        return self.inner.policy
-
-    @property
-    def zero_ts(self) -> SyncTimestamp:
-        return self.inner.zero_ts
-
-    @property
-    def stats(self):
-        return self.inner.stats
-
-    @property
-    def latest_ts(self):
-        return self.inner.latest_ts
-
-    def ts_geq(self, a, b) -> bool:
-        return self.inner.ts_geq(a, b)
-
-    def snapshot_cache_ok(self, key, version, at_ts, last_sync_ts) -> bool:
-        return self.inner.snapshot_cache_ok(key, version, at_ts, last_sync_ts)
-
-    def begin(self, last_sync_ts, cached_keys=None, policy=None):
-        self._rpc("begin")
-        return self.inner.begin(last_sync_ts, cached_keys, policy)
-
-    def fetch_blocks(self, keys, at_ts=None):
-        self._rpc("fetch_blocks")
-        return self.inner.fetch_blocks(keys, at_ts)
-
-    def fetch_metas(self, fids, at_ts=None):
-        self._rpc("fetch_meta")
-        return self.inner.fetch_metas(fids, at_ts)
-
-    def lookup_many(self, paths, at_ts=None):
-        self._rpc("lookup")
-        return self.inner.lookup_many(paths, at_ts)
-
-    def sync_files(self, reqs):
-        self._rpc("sync_files")
-        return self.inner.sync_files(reqs)
-
-    def fetch_block(self, key, at_ts=None):
-        self._rpc("fetch_block")
-        return self.inner.fetch_block(key, at_ts)
-
-    def fetch_meta(self, fid, at_ts=None):
-        self._rpc("fetch_meta")
-        return self.inner.fetch_meta(fid, at_ts)
-
-    def lookup(self, path, at_ts=None):
-        self._rpc("lookup")
-        return self.inner.lookup(path, at_ts)
-
-    def sync_file(self, fid, known_versions):
-        self._rpc("sync_file")
-        return self.inner.sync_file(fid, known_versions)
-
-    def listdir(self, prefix, at_ts=None):
-        self._rpc("listdir")
-        return self.inner.listdir(prefix, at_ts)
-
-    def commit(self, payload) -> CommitReply:
-        self._rpc("commit")
-        return self.inner.commit(payload)
-
-    def alloc_file_id(self) -> FileId:
-        self._rpc("alloc_file_id")
-        return self.inner.alloc_file_id()

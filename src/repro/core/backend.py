@@ -17,11 +17,9 @@ horizontally sharded backend. It provides:
     window are validated and applied under ONE commit-lock acquisition
     (and one durable-log write), amortizing the per-commit critical
     section,
-  * optional real durability: attach a ``repro.core.wal.WriteAheadLog``
+  * optional durability: attach a ``repro.core.wal.WriteAheadLog``
     (``self.wal``) and every commit's effects are appended and fsync'd
-    before the commit is acknowledged — ``commit_service_s`` then stays 0
-    and the *simulated* log cost is replaced by the real one. Group
-    commit amortizes the fsync exactly as it amortized the simulation.
+    before the commit is acknowledged. Group commit amortizes the fsync.
 
 The commit path is decomposed into ``validate_locked`` / ``next_ts_locked``
 / ``apply_locked`` / ``undo_locked`` / ``log_commit_locked`` so a
@@ -34,9 +32,6 @@ eager/lazy protocols guarantee this). Because we also allow the 'stale'
 policy (backend does nothing at begin; paper §4.2 explicitly permits this),
 we validate against the *observed* version timestamp instead — equivalent
 under begin-sync, and still strictly serializable without it.
-
-Transport note: simulated network latency is no longer injected here;
-wrap the backend in ``repro.core.api.LatencyInjector`` instead.
 """
 from __future__ import annotations
 
@@ -156,7 +151,7 @@ class _GroupCommitter:
     """Accumulate commit payloads for a short window; the first arrival
     becomes the batch leader, sleeps out the window, then validates and
     applies the whole batch under ONE commit-lock acquisition (and one
-    simulated durable-log write). Later payloads in a batch validate
+    WAL fsync when a log is attached). Later payloads in a batch validate
     against the state left by earlier ones — exactly the serial order
     their commit timestamps record."""
 
@@ -227,8 +222,8 @@ class _GroupCommitter:
                     except Conflict as e:
                         p.error = e
                         p.done.set()  # aborts need no durability barrier
-                # ONE durable-log write (real WAL fsync or simulated cost)
-                # for the whole batch, then acknowledge every commit in it.
+                # ONE durability barrier (the WAL's fsync) for the whole
+                # batch, then acknowledge every commit in it.
                 try:
                     be._durable_barrier()
                 except BaseException as e:
@@ -266,17 +261,12 @@ class BackendService(BackendAPI):
         hot_threshold: int = 3,
         log_horizon: int = 4096,
         group_commit_window_s: float = 0.0,
-        commit_service_s: float = 0.0,
         wal=None,
     ):
         self.store = BlockStore(block_size, versions_kept)
         self.policy = policy
         self.hot_threshold = hot_threshold
         self.log_horizon = log_horizon
-        # simulated backend-side durable-apply time (e.g. log fsync),
-        # paid once per commit-lock acquisition — what group commit
-        # amortizes. 0 in tests. Superseded by a real WAL when attached.
-        self.commit_service_s = commit_service_s
         # optional repro.core.wal.WriteAheadLog; when set, commits append
         # their effects and fsync before acking (see _durable_barrier)
         self.wal = wal
@@ -308,10 +298,6 @@ class BackendService(BackendAPI):
     def block_size(self) -> int:
         return self.store.block_size
 
-    def _service(self) -> None:
-        if self.commit_service_s:
-            time.sleep(self.commit_service_s)
-
     def _wal_append(self, payload: TxnPayload, ts: Timestamp):
         """Buffered append of this commit's effects; returns the LSN for
         the durability barrier (None when no WAL is attached)."""
@@ -324,16 +310,10 @@ class BackendService(BackendAPI):
         )
 
     def _durable_barrier(self, lsn=None) -> None:
-        """Make everything appended so far durable before acking: real
-        WAL fsync when attached, else the simulated service time. An
-        explicitly configured service time ALSO applies on top of a real
-        WAL — benchmarks use it to model slower durable media than the
-        local disk while still exercising the real log path."""
+        """Make everything appended so far durable before acking: the
+        WAL's fsync when one is attached, else nothing."""
         if self.wal is not None:
             self.wal.sync(lsn)
-            self._service()
-        else:
-            self._service()
 
     # ------------------------------------------------------------------ #
     # sequencer

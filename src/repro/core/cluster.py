@@ -1192,7 +1192,7 @@ class ClusterBackend(BackendAPI):
 
 
 # --------------------------------------------------------------------------- #
-# subprocess harness (tests + benchmarks)
+# subprocess harness (tests)
 # --------------------------------------------------------------------------- #
 class ClusterHarness:
     """Spawn a real cluster — N shard server processes + a coordinator
@@ -1209,7 +1209,6 @@ class ClusterHarness:
         policy: str = "invalidate",
         admin_token: Optional[str] = "cluster-secret",
         name_by_parent: bool = False,
-        commit_service_s: float = 0.0,
         checkpoint_records: Optional[int] = None,
         startup_timeout_s: float = 30.0,
     ):
@@ -1220,7 +1219,6 @@ class ClusterHarness:
         self.policy = policy
         self.admin_token = admin_token
         self.name_by_parent = name_by_parent
-        self.commit_service_s = commit_service_s
         self.checkpoint_records = checkpoint_records
         self.startup_timeout_s = startup_timeout_s
         self.shard_procs: List[Optional[subprocess.Popen]] = []
@@ -1278,8 +1276,6 @@ class ClusterHarness:
             argv += ["--admin-token", self.admin_token]
         if self.name_by_parent:
             argv += ["--name-by-parent"]
-        if self.commit_service_s:
-            argv += ["--commit-service", str(self.commit_service_s)]
         if self.checkpoint_records is not None:
             argv += ["--checkpoint-records", str(self.checkpoint_records)]
         if self.coord_port:

@@ -664,19 +664,14 @@ def attach_lease_tier(
     Dispatches on the backend kind: a ``RemoteBackend`` gets wire leases
     + push frames; a cluster client leases via its coordinator
     connection (commits serialize there, so its pushes cover every
-    shard); in-proc backends subscribe to the commit-effects broker; a
-    ``LatencyInjector`` (or any wrapper exposing ``.inner``) is
-    unwrapped first. A backend with no coherence channel still gets
-    working views — the staleness bound alone governs them."""
+    shard); in-proc backends subscribe to the commit-effects broker. A
+    backend with no coherence channel still gets working views — the
+    staleness bound alone governs them."""
     existing = getattr(local, "lease_tier", None)
     if existing is not None:
         return existing
     tier = LeaseTier(local, max_staleness_s, mode, lease_ttl_s)
     be = local.backend
-    hops = 0
-    while hasattr(be, "inner") and hops < 8:
-        be = be.inner
-        hops += 1
     from repro.core.remote import RemoteBackend  # lazy: import cycles
 
     coord = getattr(be, "coord", None)

@@ -13,9 +13,6 @@ Design points:
     in flight on one socket and replies may arrive out of order as
     server handlers finish. ``submit(op, *args)`` exposes the pipeline
     to callers; the blocking methods are just ``submit(...).result()``.
-    This replaces PR 2's pool-per-in-flight-request model
-    (``PooledRemoteBackend`` below survives only so ``bench_remote`` can
-    keep measuring the old design against the new one).
   * **Batch ops are one frame.** ``fetch_blocks`` / ``fetch_metas`` /
     ``lookup_many`` / ``sync_files`` ship the whole batch in a single
     request; against a sharded server the fan-out and merge run
@@ -49,7 +46,6 @@ from __future__ import annotations
 
 import socket
 import threading
-from contextlib import contextmanager
 from typing import Any, Callable, Dict, List, Optional, Set, Tuple
 
 from repro.core import obs, wire
@@ -78,11 +74,30 @@ _PUSHES_DROPPED = obs.REGISTRY.counter(
 _Decoder = Optional[Callable[[Any], Any]]
 
 
-class _RemoteCore(BackendAPI):
-    """Handshake, timestamp algebra, lease-based id allocation, and the
-    RPC encode/decode surface — shared by the multiplexed client and the
-    legacy pooled client. Subclasses provide ``_call`` (one blocking
-    frame exchange)."""
+class RemoteBackend(BackendAPI):
+    """Multiplexed, pipelined transport (the default).
+
+    ``submit(op, *args)`` puts the request on the wire and returns a
+    ``BackendFuture`` immediately; replies (possibly out of order) are
+    matched back to futures by request id. Blocking calls are futures
+    the caller waits on — one code path either way.
+
+    **Serial fast path.** Receiving is lease-based: whichever thread
+    blocks first on an unresolved future takes the *reader lease* and
+    recvs replies itself, resolving every future whose reply it sees —
+    a serial RPC therefore completes on the calling thread with zero
+    extra wakeups (the pre-PR-6 design crossed ~2: reader-thread recv,
+    then event hand-off to the caller). A standing reader thread still
+    exists, but parked: it only reads when woken for timed waits or
+    hand-offs, plus a low-frequency opportunistic drain that catches
+    unsolicited frames (stray replies, server FIN) while no caller is
+    waiting."""
+
+    #: parked-reader tick: how often the standing reader opportunistically
+    #: drains the socket when nobody holds the lease
+    IDLE_TICK = 0.05
+    #: follower retry tick while another thread holds the reader lease
+    FOLLOW_TICK = 0.05
 
     def __init__(self, host: str, port: int, lease_size: int = DEFAULT_LEASE,
                  connect_timeout_s: float = 10.0,
@@ -101,13 +116,31 @@ class _RemoteCore(BackendAPI):
         self.reconnects = 0
         self.disconnects = 0
         self._closed = False
-
-    # -- transport hook ------------------------------------------------ #
-    def _call(self, msg_type: int, obj: Any, decode: _Decoder = None) -> Any:
-        raise NotImplementedError
-
-    def close(self) -> None:
-        raise NotImplementedError
+        self._mu = threading.Lock()          # conn state + pending table
+        self._send_mu = threading.Lock()     # guards the send buffer
+        self._write_mu = threading.Lock()    # serializes socket writes
+        self._send_buf = bytearray()         # frames awaiting a flush
+        self._send_sock: Optional[socket.socket] = None
+        self._sock: Optional[socket.socket] = None
+        self._rdr: Optional[wire.FrameReader] = None
+        self._reader: Optional[threading.Thread] = None
+        self._rx_lease = threading.Lock()    # whoever holds it recvs
+        self._rx_wake = threading.Event()    # kicks the parked reader
+        self._next_id = 1
+        self._pending: Dict[int, Tuple[BackendFuture, _Decoder]] = {}
+        self._push_handler: Optional[Callable[[int, Any], None]] = None
+        self.pushes = 0          # server-initiated frames delivered
+        self.pushes_dropped = 0  # server-initiated frames w/o a handler
+        self.stray_replies = 0   # unknown/duplicate request ids observed
+        self.flushes = 0         # coalesced sends actually performed
+        self.lease_completions = 0   # replies read by a waiting caller
+        self.parked_completions = 0  # replies read by the parked reader
+        self._rdr_base = 0       # bytes_copied carried over dead readers
+        self._sunk_base = 0      # bytes_sunk carried over dead readers
+        self._frames_base = 0    # frame count carried over dead readers
+        # eager dial: surfaces connection/handshake errors at construction
+        with self._mu:
+            self._connect_locked()
 
     def _handshake(self, sock: socket.socket) -> None:
         try:
@@ -405,63 +438,6 @@ class _RemoteCore(BackendAPI):
         forward-compatible extra key (old clients just ignore it)."""
         s = self.stats
         return getattr(s, "extra", {}).get("metrics", {})
-
-
-class RemoteBackend(_RemoteCore):
-    """Multiplexed, pipelined transport (the default).
-
-    ``submit(op, *args)`` puts the request on the wire and returns a
-    ``BackendFuture`` immediately; replies (possibly out of order) are
-    matched back to futures by request id. Blocking calls are futures
-    the caller waits on — one code path either way.
-
-    **Serial fast path.** Receiving is lease-based: whichever thread
-    blocks first on an unresolved future takes the *reader lease* and
-    recvs replies itself, resolving every future whose reply it sees —
-    a serial RPC therefore completes on the calling thread with zero
-    extra wakeups (the pre-PR-6 design crossed ~2: reader-thread recv,
-    then event hand-off to the caller). A standing reader thread still
-    exists, but parked: it only reads when woken for timed waits or
-    hand-offs, plus a low-frequency opportunistic drain that catches
-    unsolicited frames (stray replies, server FIN) while no caller is
-    waiting."""
-
-    #: parked-reader tick: how often the standing reader opportunistically
-    #: drains the socket when nobody holds the lease
-    IDLE_TICK = 0.05
-    #: follower retry tick while another thread holds the reader lease
-    FOLLOW_TICK = 0.05
-
-    def __init__(self, host: str, port: int, lease_size: int = DEFAULT_LEASE,
-                 connect_timeout_s: float = 10.0,
-                 admin_token: Optional[str] = None):
-        super().__init__(host, port, lease_size, connect_timeout_s,
-                         admin_token=admin_token)
-        self._mu = threading.Lock()          # conn state + pending table
-        self._send_mu = threading.Lock()     # guards the send buffer
-        self._write_mu = threading.Lock()    # serializes socket writes
-        self._send_buf = bytearray()         # frames awaiting a flush
-        self._send_sock: Optional[socket.socket] = None
-        self._sock: Optional[socket.socket] = None
-        self._rdr: Optional[wire.FrameReader] = None
-        self._reader: Optional[threading.Thread] = None
-        self._rx_lease = threading.Lock()    # whoever holds it recvs
-        self._rx_wake = threading.Event()    # kicks the parked reader
-        self._next_id = 1
-        self._pending: Dict[int, Tuple[BackendFuture, _Decoder]] = {}
-        self._push_handler: Optional[Callable[[int, Any], None]] = None
-        self.pushes = 0          # server-initiated frames delivered
-        self.pushes_dropped = 0  # server-initiated frames w/o a handler
-        self.stray_replies = 0   # unknown/duplicate request ids observed
-        self.flushes = 0         # coalesced sends actually performed
-        self.lease_completions = 0   # replies read by a waiting caller
-        self.parked_completions = 0  # replies read by the parked reader
-        self._rdr_base = 0       # bytes_copied carried over dead readers
-        self._sunk_base = 0      # bytes_sunk carried over dead readers
-        self._frames_base = 0    # frame count carried over dead readers
-        # eager dial: surfaces connection/handshake errors at construction
-        with self._mu:
-            self._connect_locked()
 
     # ------------------------------------------------------------------ #
     # connection lifecycle
@@ -855,62 +831,3 @@ class RemoteBackend(_RemoteCore):
 
     def _call(self, msg_type: int, obj: Any, decode: _Decoder = None) -> Any:
         return self.submit_frame(msg_type, obj, decode).result()
-
-
-class PooledRemoteBackend(_RemoteCore):
-    """PR 2's pool-per-in-flight-request transport, kept ONLY as the
-    benchmark baseline (``bench_remote`` pooled-vs-pipelined rows) — one
-    synchronous request per checked-out connection, concurrency by
-    growing the pool."""
-
-    def __init__(self, host: str, port: int, lease_size: int = DEFAULT_LEASE,
-                 connect_timeout_s: float = 10.0,
-                 admin_token: Optional[str] = None):
-        super().__init__(host, port, lease_size, connect_timeout_s,
-                         admin_token=admin_token)
-        self._pool: List[socket.socket] = []
-        self._pool_mu = threading.Lock()
-        with self._pool_mu:
-            self._pool.append(self._dial())
-
-    @contextmanager
-    def _conn(self):
-        with self._pool_mu:
-            sock = self._pool.pop() if self._pool else None
-        if sock is None:
-            sock = self._dial()
-        try:
-            yield sock
-        except BaseException:
-            try:
-                sock.close()
-            except OSError:
-                pass
-            raise
-        else:
-            with self._pool_mu:
-                if self._closed:
-                    sock.close()
-                else:
-                    self._pool.append(sock)
-
-    def _call(self, msg_type: int, obj: Any, decode: _Decoder = None) -> Any:
-        self.rpcs += 1
-        with self._conn() as sock:
-            wire.send_frame(sock, msg_type, obj, 1)
-            reply_type, _, reply = wire.recv_frame(sock)
-        if reply_type == wire.T_OK:
-            return reply if decode is None else decode(reply)
-        if reply_type == wire.T_ERR:
-            raise wire.exception_from_obj(reply)
-        raise wire.WireError(f"unexpected reply type 0x{reply_type:02x}")
-
-    def close(self) -> None:
-        with self._pool_mu:
-            self._closed = True
-            conns, self._pool = self._pool, []
-        for sock in conns:
-            try:
-                sock.close()
-            except OSError:
-                pass

@@ -38,9 +38,6 @@ Semantics:
   function as a writer.
 * **Stats.** Pass ``stats=InvocationStats()`` for one invocation's
   numbers; ``runtime.stats`` aggregates across all invocations.
-
-``repro.core.retry.run_function`` survives as a thin deprecated shim
-over ``FunctionRuntime.invoke``.
 """
 from __future__ import annotations
 
@@ -368,10 +365,10 @@ def runtime_for(target, **kwargs) -> FunctionRuntime:
     """Coerce a ``LocalServer`` or ``FunctionRuntime`` to a runtime.
 
     The ML-state layers (``CheckpointManager``, ``PagedKVCache``,
-    ``SnapshotServer``) accept either so legacy call sites that hold a
-    bare ``LocalServer`` keep working after the ``run_function``
-    deprecation; a runtime built here is cached on the server, so every
-    layer sharing one worker shares one runtime (and its stats)."""
+    ``SnapshotServer``) accept either, so call sites that hold a bare
+    ``LocalServer`` work too; a runtime built here is cached on the
+    server, so every layer sharing one worker shares one runtime (and
+    its stats)."""
     if isinstance(target, FunctionRuntime):
         return target
     rt = getattr(target, "_default_runtime", None)

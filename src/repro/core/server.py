@@ -1200,14 +1200,12 @@ def make_backend(
     slots: Optional[List[int]] = None,
     n_slots: Optional[int] = None,
     name_by_parent: bool = False,
-    commit_service_s: float = 0.0,
 ) -> BackendAPI:
     kwargs = dict(
         block_size=block_size,
         policy=CachePolicy(policy),
         versions_kept=versions_kept,
         group_commit_window_s=group_commit_window_s,
-        commit_service_s=commit_service_s,
     )
     if n_shards <= 0 and slots is None and n_slots is None:
         return BackendService(**kwargs)
@@ -1273,9 +1271,6 @@ def main(argv=None) -> None:
     p.add_argument("--coordinator", default=None,
                    help="host:port of the cluster coordinator, used to "
                         "resolve in-doubt 2PC txids after a crash restart")
-    p.add_argument("--commit-service", type=float, default=0.0,
-                   help="simulated per-commit service time in seconds "
-                        "(benchmarks only)")
     p.add_argument("--crash-at", default=None,
                    help="failpoint name: SIGKILL this process the moment "
                         "the named crash point is reached (tests only)")
@@ -1297,7 +1292,6 @@ def main(argv=None) -> None:
         group_commit_window_s=args.group_window,
         slots=slots, n_slots=args.n_slots,
         name_by_parent=args.name_by_parent,
-        commit_service_s=args.commit_service,
     )
     server = BackendServer(
         backend, host=args.host, port=args.port,

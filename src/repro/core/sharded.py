@@ -33,10 +33,9 @@ effects anywhere (a multi-shard read transaction not marked read-only:
 pure validation) finishes right there — it serializes at the validation
 point, burns no timestamps, and releases immediately. Effectful
 transactions get each effectful shard's next local timestamp and apply
-**in parallel** (one thread per shard — overlapping the per-shard
-durable-apply cost), the commit is logged as ONE atomic WAL record when
-a log is attached, the sync vector registers all participants
-atomically, and every lock releases. A Conflict on any shard aborts the
+**in parallel** (one thread per shard), the commit is logged as ONE
+atomic WAL record when a log is attached, the sync vector registers all
+participants atomically, and every lock releases. A Conflict on any shard aborts the
 whole transaction before anything applies; an unexpected apply failure
 rolls already-applied shards back through their undo chains.
 Single-shard transactions — the common case by construction — take the
@@ -125,7 +124,6 @@ class ShardedBackend(BackendAPI):
         hot_threshold: int = 3,
         log_horizon: int = 4096,
         group_commit_window_s: float = 0.0,
-        commit_service_s: float = 0.0,
         wal=None,
         slots: Optional[List[int]] = None,
         n_slots: Optional[int] = None,
@@ -153,7 +151,6 @@ class ShardedBackend(BackendAPI):
             hot_threshold=hot_threshold,
             log_horizon=log_horizon,
             group_commit_window_s=group_commit_window_s,
-            commit_service_s=commit_service_s,
         )
         self.shards: Dict[int, BackendService] = {}
         self._vec_lock = threading.Lock()
@@ -697,15 +694,13 @@ class ShardedBackend(BackendAPI):
             # a non-serializable cut (see the module docstring).
 
             # ---- phase 2: apply effectful shards in parallel (one thread
-            # per shard overlaps their durable-apply service time), undo on
-            # unexpected failure ----
+            # per shard), undo on unexpected failure ----
             ts_map = {s: svcs[s].next_ts_locked() for s in eff}
             applied: Dict[int, Touched] = {}
             failures: List[BaseException] = []
 
             def apply_on(s: int) -> None:
                 try:
-                    svcs[s]._service()
                     applied[s] = svcs[s].apply_locked(
                         parts[s], ts_map[s]
                     )
@@ -845,9 +840,7 @@ class ShardedBackend(BackendAPI):
                 if st["parts"] is not None:
                     applied: Dict[int, Touched] = {}
                     for s in st["eff"]:
-                        sh = self.shards[s]
-                        sh._service()
-                        applied[s] = sh.apply_locked(
+                        applied[s] = self.shards[s].apply_locked(
                             st["parts"][s], st["ts"][s]
                         )
                     for s in st["eff"]:

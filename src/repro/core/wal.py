@@ -1,12 +1,9 @@
 """Durable write-ahead commit log + checkpointing + crash recovery.
 
-Until now the backend only *modeled* durability: ``commit_service_s``
-charged a simulated log-fsync per commit-lock acquisition, and group
-commit amortized that simulated cost per batch. This module makes the
-real path real: on validate-success the commit's effects are appended to
-an on-disk log and fsync'd **before the client's commit is acknowledged**,
-so an acked commit survives a server crash. Group commit keeps its role
-unchanged — many appends, one fsync.
+On validate-success the commit's effects are appended to an on-disk log
+and fsync'd **before the client's commit is acknowledged**, so an acked
+commit survives a server crash. Group commit amortizes the fsync: many
+appends, one fsync.
 
 Three layers live here:
 

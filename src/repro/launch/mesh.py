@@ -1,17 +1,12 @@
 """Production mesh definitions.
 
 A function, not a module-level constant: importing this module never touches
-jax device state. The dry-run sets XLA_FLAGS for 512 placeholder host devices
+jax device state. A caller that wants placeholder host devices sets XLA_FLAGS
 BEFORE importing jax; everything else sees the real device count.
 """
 from __future__ import annotations
 
 import jax
-
-# v5e hardware constants used by the roofline analysis
-PEAK_FLOPS_BF16 = 197e12        # per chip
-HBM_BW = 819e9                  # bytes/s per chip
-ICI_BW = 50e9                   # bytes/s per link
 
 # Latency-hiding / async-collective flags we would set on real TPU pods
 # (recorded here; harmless on CPU):

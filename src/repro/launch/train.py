@@ -3,7 +3,7 @@
 On this CPU container it runs the REDUCED same-family config through the
 full transactional stack (FaaSFS-backed state, delta checkpoints, OCC
 retry); on a real pod the same driver takes ``--full`` and the production
-mesh (the step function and shardings are exactly the dry-run's).
+mesh (the step function and shardings ``lower_cell`` builds).
 
   PYTHONPATH=src python -m repro.launch.train --arch qwen2-1.5b --steps 20
 """

@@ -1,7 +1,7 @@
 """GQA attention: full/causal/sliding-window, chunked long-seq path, decode.
 
 The ``xla`` implementation here is the pure-jnp reference used for CPU tests
-and the dry-run; on TPU the flash-attention Pallas kernel
+and compile-only lowering; on TPU the flash-attention Pallas kernel
 (`repro.kernels.flash_attention`) replaces the core softmax(QK^T)V when
 ``impl="pallas"``.
 """

@@ -2,7 +2,7 @@
 
 A single table of ``ParamDef``s per architecture drives three things:
   * real initialization (smoke tests, the 100M training example),
-  * abstract ``ShapeDtypeStruct`` trees (the multi-pod dry-run),
+  * abstract ``ShapeDtypeStruct`` trees (compile-only lowering),
   * logical-axis -> mesh-axis sharding specs (pjit in/out shardings).
 
 The decoder stack is a ``lax.scan`` over layer-stacked parameters with

@@ -1,6 +1,6 @@
 """Step builders + sharding rules: the bridge between model code and pjit.
 
-Everything the dry-run, trainer and server need for one (arch x shape x mesh)
+Everything the trainer, the server and the compile tests need for one (arch x shape x mesh)
 cell: abstract input/state trees with NamedShardings attached, and the jit'd
 ``train_step`` / ``prefill_step`` / ``decode_step`` with in/out shardings.
 """
@@ -36,31 +36,6 @@ def train_rules(fsdp_axis: Any = "data", tensor_axis: str = "model") -> Dict:
         "experts": tensor_axis,
         "expert_ffn": tensor_axis,  # fallback when E doesn't divide (mixtral)
         "dinner": tensor_axis,
-        "layers": None,
-        None: None,
-    }
-
-
-def train_rules_v2() -> Dict:
-    """§Perf iteration: FSDP over OUTPUT dims only.
-
-    Baseline v1 shards the weights' d_model (contraction) dim over ``data``,
-    which XLA sometimes lowers as partial-matmul + output all-reduce instead
-    of a weight all-gather (measured: 493 GB/step of projection all-reduce
-    on deepseek-33b). v2 keeps contraction dims unsharded and spreads the
-    output dims over ("data","model"), so the only way to compute is to
-    all-gather the (much smaller) weight shard — and weight grads
-    reduce-scatter naturally (ZeRO). Per-device weight memory is identical.
-    """
-    return {
-        "vocab": ("data", "model"),
-        "residual": None,
-        "heads": ("data", "model"),
-        "kv": ("data", "model"),
-        "ffn": ("data", "model"),
-        "experts": "model",
-        "expert_ffn": "data",
-        "dinner": ("data", "model"),
         "layers": None,
         None: None,
     }

@@ -1,5 +1,5 @@
 """FunctionRuntime: implicit transactions, conflict restart, read-only
-inference, warm-container cache semantics, and the run_function shim."""
+inference, and warm-container cache semantics."""
 import pytest
 
 from repro.core.client import LocalServer
@@ -203,43 +203,3 @@ def test_invoke_plain_callable_and_kwargs(backend):
         return fs.fstat(fd)["st_size"]
 
     assert runtime.invoke(fn, "/mnt/tsfs/k", data=b"abc") == 3
-
-
-def test_run_function_shim_is_deprecated_but_works(backend):
-    from repro.core.retry import run_function
-
-    local = LocalServer(backend)
-
-    def fn(fs: FaaSFS):
-        fd = fs.open("/mnt/tsfs/shim", O_CREAT | O_RDWR)
-        fs.write(fd, b"legacy")
-        return "ok"
-
-    stats = InvocationStats()
-    with pytest.warns(DeprecationWarning):
-        assert run_function(local, fn, stats=stats) == "ok"
-    assert stats.attempts == 1 and stats.commit_ts
-
-    def check(fs: FaaSFS):
-        fd = fs.open("/mnt/tsfs/shim")
-        return fs.pread(fd, 6, 0)
-
-    with pytest.warns(DeprecationWarning):
-        assert run_function(local, check, read_only=True) == b"legacy"
-
-
-def test_no_run_function_callers_remain_in_src():
-    """The deprecation is finished: ``repro.core.retry`` itself is the
-    ONLY module in ``src/repro`` still naming ``run_function`` — every
-    state/serving/core consumer runs on ``FunctionRuntime``."""
-    from pathlib import Path
-
-    root = Path(__file__).resolve().parents[2] / "src" / "repro"
-    offenders = []
-    for p in sorted(root.rglob("*.py")):
-        if p.name == "retry.py":
-            continue  # the shim itself
-        for i, line in enumerate(p.read_text().splitlines(), 1):
-            if "run_function(" in line and not line.lstrip().startswith("#"):
-                offenders.append(f"{p.relative_to(root)}:{i}")
-    assert offenders == []

@@ -311,9 +311,9 @@ def test_2pc_read_participant_lock_pins_the_cut():
 
 
 def test_2pc_applies_shards_in_parallel():
-    """Per-shard durable apply overlaps across 2PC participants: both
-    effectful shards must be inside their service window simultaneously
-    (a serial apply would deadlock the barrier and fail the commit)."""
+    """2PC phase 2 applies the effectful participants concurrently: both
+    shards must be inside ``apply_locked`` at once (a serial apply would
+    leave the barrier one party short and fail the commit)."""
     be = ShardedBackend(n_shards=2, block_size=16)
     a = LocalServer(be)
     f1 = new_file(a, "/x", size=16)
@@ -322,12 +322,15 @@ def test_2pc_applies_shards_in_parallel():
 
     rendezvous = threading.Barrier(2)
 
-    def overlapping_service():
-        # passes only if BOTH shard applies are in flight concurrently
-        rendezvous.wait(timeout=5)
+    def overlapping(apply_locked):
+        def wrapped(*args, **kwargs):
+            # passes only if BOTH shard applies are in flight concurrently
+            rendezvous.wait(timeout=5)
+            return apply_locked(*args, **kwargs)
+        return wrapped
 
     for s in (be.shard_of_fid(f1), be.shard_of_fid(f2)):
-        be.shards[s]._service = overlapping_service
+        be.shards[s].apply_locked = overlapping(be.shards[s].apply_locked)
 
     txn = a.begin()
     txn.write(f1, 0, b"PPPP")

@@ -1,6 +1,6 @@
 """Numeric equivalence of the shard_map all-to-all MoE vs the global-view
 dispatch — run on a 4-device forced-host mesh in a subprocess (the main test
-process keeps 1 device; see dryrun.py notes)."""
+process keeps 1 device)."""
 import subprocess
 import sys
 import textwrap

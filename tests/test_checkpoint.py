@@ -59,6 +59,29 @@ def test_delta_checkpoint_ships_fewer_bytes(local):
     assert restored["params"]["w"][0, 0] == 9.0
 
 
+def test_delta_save_of_a_one_percent_run_writes_at_most_5pct_of_the_state(local):
+    """1% of a float32 leaf's elements change as one contiguous run at a
+    seeded offset (an updated layer slab or embedding rows): the delta
+    save writes at most 5% of the full state's bytes, so its cost follows
+    the write rate, not the state size. (A scattered 1% change would
+    dirty every block.)"""
+    rng = np.random.default_rng(20090)
+    w = rng.normal(size=1 << 18).astype(np.float32)  # 1 MiB, 256 blocks
+    cm = CheckpointManager(local, block_bytes=4096)
+    full = cm.save(1, {"w": w})
+    assert full.bytes_written == w.nbytes
+    n = w.size // 100
+    lo = int(rng.integers(0, w.size - n + 1))
+    w2 = w.copy()
+    w2[lo:lo + n] += rng.normal(size=n).astype(np.float32) * 0.01
+    delta = cm.save(2, {"w": w2})
+    assert delta.dirty is not None
+    assert delta.bytes_written <= 0.05 * w.nbytes
+    restored, step = cm.restore({"w": np.zeros_like(w)})
+    assert step == 2
+    np.testing.assert_array_equal(restored["w"], w2)
+
+
 def test_restore_from_second_worker(local):
     cm = CheckpointManager(local)
     cm.save(5, state(5.0))

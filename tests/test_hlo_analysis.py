@@ -78,7 +78,7 @@ def test_nested_scan():
 def test_collective_bytes_on_spmd_program():
     import os
     if jax.device_count() < 4:
-        pytest.skip("needs multi-device (run under dryrun env)")
+        pytest.skip("needs 4 devices (XLA_FLAGS=--xla_force_host_platform_device_count=4)")
 
 
 def test_collective_formulas_via_mock_hlo():
