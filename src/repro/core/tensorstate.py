@@ -196,6 +196,8 @@ class TensorStore:
         self.fs = fs
         self.prefix = prefix.rstrip("/")
         self.arena = arena
+        #: what the last ``load`` read (see ``load``)
+        self.loaded: Optional[Dict[str, Any]] = None
 
     # ------------------------------------------------------------------ #
     def _meta_path(self, name: str) -> str:
@@ -270,11 +272,14 @@ class TensorStore:
         return (name, meta) if ref is None else (ref, self.read_meta(ref))
 
     def _sources(self, name: str, leaf: Dict[str, Any], nbytes: int,
-                 metas: Dict[str, Dict[str, Any]]) -> List[Tuple[str, List[Tuple[int, int]]]]:
-        """Where each block of ``leaf`` lives along the base chain:
-        ``[(save name, [(start, stop) byte spans])]``, newest first."""
+                 metas: Dict[str, Dict[str, Any]],
+                 need: Optional[Iterable[int]] = None,
+                 ) -> List[Tuple[str, List[Tuple[int, int]]]]:
+        """Where each block of ``leaf`` (or each of ``need``, default all)
+        lives along the base chain: ``[(save name, [(start, stop) byte
+        spans])]``, newest first."""
         bb = metas[name]["block_bytes"]
-        need = set(range(-(-nbytes // bb)))
+        need = set(range(-(-nbytes // bb)) if need is None else need)
         out = []
         cur, entry = name, leaf
         seen = {name}
@@ -299,7 +304,46 @@ class TensorStore:
                 raise NotFound(f"{cur}: leaf {leaf['name']} missing")
         return out
 
-    def load(self, name: str, *, zero_copy: bool = False) -> Dict[str, np.ndarray]:
+    def _changed_since(self, name: str, metas: Dict[str, Dict[str, Any]],
+                       held: Tuple[str, Dict[str, np.ndarray]],
+                       ) -> Optional[Tuple[int, Dict[str, set]]]:
+        """``(versions, {leaf: blocks})``: the versions of ``name``'s chain
+        newer than the held one, and per leaf the union of the blocks they
+        wrote. ``None`` where the held arrays cannot stand for the blocks
+        left out: an in-place save on either side (it is rewritten under
+        its name), a chain that ends before the held version, or a leaf
+        that is new or of another dtype or shape."""
+        held_name, flat = held
+        if "depth" not in metas[name]:
+            return None
+        wanted = {l["name"]: l for l in metas[name]["leaves"]}
+        for lname, l in wanted.items():
+            a = flat.get(lname)
+            if a is None or str(a.dtype) != l["dtype"] or \
+                    list(a.shape) != l["shape"]:
+                return None
+        dirty: Dict[str, set] = {lname: set() for lname in wanted}
+        cur, versions = name, 0
+        while cur != held_name:
+            meta = metas[cur]
+            leaves = {l["name"]: l for l in meta["leaves"]}
+            if meta.get("base") is None or not wanted.keys() <= leaves.keys():
+                return None
+            bb = meta["block_bytes"]
+            for lname in wanted:
+                l = leaves[lname]
+                n = int(np.prod(l["shape"], dtype=np.int64)) * \
+                    np.dtype(l["dtype"]).itemsize
+                dirty[lname].update(l.get("blocks", range(-(-n // bb))))
+            versions += 1
+            cur = meta["base"]
+            if cur not in metas:
+                metas[cur] = self.read_meta(cur)
+        return versions, dirty
+
+    def load(self, name: str, *, zero_copy: bool = False,
+             held: Optional[Tuple[str, Dict[str, np.ndarray]]] = None,
+             ) -> Dict[str, np.ndarray]:
         """Read all leaves as a flat {name: array} dict.
 
         ``zero_copy=True`` is the arena path: the ``.meta`` layout keys a
@@ -310,13 +354,36 @@ class TensorStore:
         ``.copy()``). Returned arrays are READONLY views over sealed
         arena buffers; they stay valid as long as any view is alive and
         the backing buffer is recycled when the last one dies. Callers
-        that need to mutate must ``.copy()``."""
-        with obs.span("tensor.load", "tensor"):
-            return self._load(name, zero_copy)
+        that need to mutate must ``.copy()``.
 
-    def _load(self, name: str, zero_copy: bool) -> Dict[str, np.ndarray]:
+        ``held`` is ``(version, flat)``: the ``version`` an earlier load
+        reported in ``loaded``, and the leaves that load returned (sealed,
+        so never written since).
+        Where ``name`` now holds a delta chained back to that version, only
+        the blocks the versions since then wrote are read: a leaf none of
+        them touched comes back as the held array itself, and another lands
+        in a new buffer as a copy of the held bytes with those blocks read
+        over it (or whole, with no copy, when they are all of its blocks).
+        Otherwise every block is read, as without ``held``.
+
+        Afterwards ``loaded`` describes the load: ``version`` (the save it
+        read), ``delta`` (1 when ``held`` was used), ``versions`` (the
+        versions newer than the held one), ``bytes_landed`` (read from the
+        file system), ``bytes_copied`` (from held leaves) and
+        ``leaves_kept`` (held arrays returned as they were)."""
+        with obs.span("tensor.load", "tensor"):
+            return self._load(name, zero_copy, held)
+
+    def _load(self, name: str, zero_copy: bool,
+              held: Optional[Tuple[str, Dict[str, np.ndarray]]],
+              ) -> Dict[str, np.ndarray]:
         name, meta = self.resolve(name)
         metas = {name: meta}
+        since = None if held is None else self._changed_since(name, metas, held)
+        versions, dirty = (0, None) if since is None else since
+        self.loaded = {"version": name, "delta": int(since is not None),
+                       "versions": versions, "bytes_landed": 0,
+                       "bytes_copied": 0, "leaves_kept": 0}
         # tensor-sized readahead: the meta layout names every leaf, so
         # one lookup_many primes the whole checkpoint's name->fid map
         # before any data moves (readdir-free, single round trip)
@@ -329,15 +396,33 @@ class TensorStore:
             arena = self.arena if self.arena is not None else default_arena()
             txn = self.fs.txn
             sunk0, copied0 = txn.bytes_sunk, txn.bytes_copied_into
+        bb = meta["block_bytes"]
         for leaf in meta["leaves"]:
             dt = np.dtype(leaf["dtype"])
             count = int(np.prod(leaf["shape"], dtype=np.int64)) \
                 if leaf["shape"] else 1
             n = count * dt.itemsize
-            srcs = self._sources(name, leaf, n, metas)
-            with obs.span("tensor.land", "tensor", args={"bytes": n}):
-                if arena is not None:
+            need = None if dirty is None else \
+                {b for b in dirty[leaf["name"]] if b * bb < n}
+            keep = need is not None and not need
+            # the held bytes stand for the blocks no newer version wrote
+            old = None if keep or need is None or len(need) * bb >= n \
+                else _leaf_u8(held[1][leaf["name"]])
+            srcs = [] if keep else self._sources(
+                name, leaf, n, metas, None if old is None else need)
+            args = {"bytes": n, "landed": sum(hi - lo for _, spans in srcs
+                                              for lo, hi in spans),
+                    "copied": 0 if old is None else n}
+            self.loaded["bytes_landed"] += args["landed"]
+            self.loaded["bytes_copied"] += args["copied"]
+            with obs.span("tensor.land", "tensor", args=args):
+                if keep:
+                    self.loaded["leaves_kept"] += 1
+                    out[leaf["name"]] = held[1][leaf["name"]]
+                elif arena is not None:
                     buf = arena.alloc(n, round_to=self.fs.txn.block_size)
+                    if old is not None:
+                        buf.view(0, n)[:] = old
                     for src, spans in srcs:
                         fd = self.fs.open(self._leaf_path(src, leaf["name"]))
                         for lo, hi in spans:
@@ -355,7 +440,7 @@ class TensorStore:
                     weakref.finalize(root, buf.release)
                     out[leaf["name"]] = root.reshape(leaf["shape"])
                 else:
-                    data = bytearray(n)
+                    data = bytearray(n if old is None else old)
                     for src, spans in srcs:
                         fd = self.fs.open(self._leaf_path(src, leaf["name"]))
                         for lo, hi in spans:

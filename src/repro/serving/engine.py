@@ -3,13 +3,17 @@
 A serving replica pins a committed version (the paper's snapshot reads):
 requests are served from a consistent parameter snapshot even while training
 transactions keep committing. ``refresh()`` advances to the newest committed
-version by reloading the whole state zero-copy (``TensorStore.load``): every
-block lands in an arena buffer again, changed or not.
+version zero-copy (``TensorStore.load``), handing in the version it holds:
+where the new one is a delta chained back to it, only the blocks written
+since land, leaves none of them touched are kept as the same arrays, and
+the rest of each touched leaf is copied from the held one. Otherwise (the
+first refresh, an in-place save, a chain cut by a full version) every
+block lands again.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Dict, Optional, Tuple
 
 from repro.core import obs
 from repro.core.client import LocalServer
@@ -46,20 +50,31 @@ class SnapshotServer:
         self.params: Optional[PyTree] = None
         self.version: int = -1
         self.stats = ServeStats()
+        # (save name, flat leaves) of the last load, for the next refresh
+        self._held: Optional[Tuple[str, Dict[str, Any]]] = None
 
     # ------------------------------------------------------------------ #
     def refresh(self) -> int:
-        """Load the latest committed snapshot, whole and zero-copy."""
+        """Load the latest committed snapshot zero-copy, landing only what
+        changed since the version held. ``replica.refresh`` carries
+        ``{delta, versions, bytes_landed, bytes_copied, leaves_kept}``
+        (``TensorStore.loaded``)."""
         holder: Dict[str, Any] = {}
 
         def do_read(fs: FaaSFS) -> None:
             store = TensorStore(fs, prefix=self.root)
-            holder["flat"] = store.load(self.name, zero_copy=True)
+            holder["flat"] = store.load(self.name, zero_copy=True,
+                                        held=self._held)
+            holder["loaded"] = store.loaded
             holder["ts"] = fs.txn.read_ts
 
-        with obs.span("replica.refresh", "replica"):
+        args: Dict[str, Any] = {}
+        with obs.span("replica.refresh", "replica", args=args):
             runtime_for(self.local).invoke(do_read, read_only=True)
             self.params = unflatten_like(self.template, holder["flat"])
+            loaded = dict(holder["loaded"])
+            self._held = (loaded.pop("version"), holder["flat"])
+            args.update(loaded)
         self.version = holder["ts"]
         self.stats.refreshes += 1
         return self.version

@@ -221,7 +221,9 @@ def test_split_publish_is_seen_whole_by_a_refreshing_replica(local, monkeypatch)
     """A save of a name replicas serve, split over several transactions,
     writes a new version: a replica refreshing after each part sees the
     old state whole until the last part commits, then the new one whole
-    — for a full rewrite and for a delta."""
+    — for a full rewrite and for a delta. Refreshing by delta, it keeps
+    every leaf while the pointer stands, and after a delta lands only the
+    leaves that delta touched."""
     from repro.core import tensorstate
     from repro.core.tensorstate import save_tree
     from repro.serving.engine import SnapshotServer
@@ -230,13 +232,14 @@ def test_split_publish_is_seen_whole_by_a_refreshing_replica(local, monkeypatch)
     rt = runtime_for(local)
     replica = SnapshotServer(LocalServer(local.backend), lambda p, b: p,
                              {"w": None, "b": None})
-    seen = []
+    seen, served = [], []
     invoke = rt.invoke
 
     def watched(fn, *args, **kwargs):
         out = invoke(fn, *args, **kwargs)
         replica.refresh()
         seen.append({k: np.array(v) for k, v in replica.params.items()})
+        served.append(dict(replica.params))
         return out
 
     def save(t, **kw):
@@ -252,12 +255,23 @@ def test_split_publish_is_seen_whole_by_a_refreshing_replica(local, monkeypatch)
     v2 = {"w": -1.0 - v1["w"], "b": np.ones(8, np.float32)}
     v3 = {"w": v2["w"].copy(), "b": v2["b"]}
     v3["w"][::128] = 7.0                      # every other 256 B block
-    for old, new, kw in ((v1, v2, {}), (v2, v3, {"baseline": v2})):
+    v4 = {"w": v3["w"].copy(), "b": v3["b"]}
+    v4["w"][64::128] = 3.0                    # the blocks v3 did not touch
+    for old, new, kw in ((v1, v2, {}), (v2, v3, {"baseline": v2}),
+                         (v3, v4, {"baseline": v3})):
         seen.clear()
+        served.clear()
+        before = dict(replica.params) if replica.params is not None else None
         stats = save(new, **kw)
         assert stats["txns"] > 1
         assert [same(s, old) for s in seen[:-1]] == [True] * (len(seen) - 1)
         assert same(seen[-1], new)
+        if kw:
+            # the old version whole, as the same arrays, until the pointer
+            # moves; then only the leaf the delta touched is new
+            assert all(p[k] is before[k] for p in served[:-1] for k in p)
+            assert served[-1]["b"] is before["b"]
+            assert served[-1]["w"] is not before["w"]
     assert stats["base"] is not None and stats["blocks_written"] == 16
 
 
@@ -296,3 +310,200 @@ def test_state_larger_than_a_frame_saves_and_loads_over_a_socket(
     finally:
         rb.close()
         server.shutdown()
+
+
+# -- delta loads: landing only the blocks written since a held version -- #
+ROOT = "/mnt/tsfs/train"
+
+
+def _publish(local, t, **kw):
+    from repro.core.tensorstate import save_tree
+
+    return save_tree(runtime_for(local), ROOT, "state", t, block_bytes=256, **kw)
+
+
+def _load(local, held=None):
+    """(flat, loaded) of one zero-copy load of the served state."""
+    out = {}
+
+    def read(fs):
+        store = TensorStore(fs, prefix=ROOT)
+        out["flat"] = store.load("state", zero_copy=True, held=held)
+        out["loaded"] = store.loaded
+
+    runtime_for(local).invoke(read, read_only=True)
+    return out["flat"], out["loaded"]
+
+
+def _state():
+    """64 blocks of ``w``, one of ``b``, four of ``e`` at 256 B a block."""
+    return {"w": np.arange(4096, dtype=np.float32),
+            "b": np.zeros(8, np.float32),
+            "e": np.ones((32, 16), np.float16)}
+
+
+def _bump(t, elems):
+    """A copy of ``t`` with the given elements of ``w`` raised by one;
+    the other leaves are the same objects."""
+    new = dict(t, w=t["w"].copy())
+    new["w"][list(elems)] += 1.0
+    return new
+
+
+def _chain(local, t, k, seed=0):
+    """Publish ``k`` deltas after ``t``, each of a few seeded elements of
+    ``w``; returns the last tree."""
+    rng = np.random.default_rng(seed)
+    for _ in range(k):
+        new = _bump(t, rng.choice(4096, 3, replace=False))
+        _publish(local, new, baseline=t)
+        t = new
+    return t
+
+
+@pytest.mark.parametrize("deltas", [1, 3, 8])
+def test_delta_load_is_bit_equal_to_a_full_load(local, deltas):
+    """From a held version, a load after 1, 3 or 8 deltas (the versions
+    between them skipped) reads only their blocks and is bit-equal to a
+    full load; untouched leaves come back as the held arrays, and the held
+    arrays stay as they were."""
+    v0 = _state()
+    _publish(local, v0)
+    held, first = _load(local)
+    before = {k: a.copy() for k, a in held.items()}
+    last = _chain(local, v0, deltas, seed=deltas)
+    flat, loaded = _load(local, held=(first["version"], held))
+    full, whole = _load(local)
+    assert loaded["version"] == whole["version"] != first["version"]
+    assert (loaded["delta"], loaded["versions"]) == (1, deltas)
+    for k in full:
+        assert flat[k].dtype == full[k].dtype and flat[k].shape == full[k].shape
+        assert flat[k].tobytes() == full[k].tobytes() == last[k].tobytes()
+        assert held[k].tobytes() == before[k].tobytes()
+        assert not flat[k].flags.writeable
+    assert flat["b"] is held["b"] and flat["e"] is held["e"]
+    assert flat["w"] is not held["w"]
+    assert loaded["leaves_kept"] == 2
+    assert loaded["bytes_copied"] == last["w"].nbytes
+    assert 0 < loaded["bytes_landed"] <= 3 * deltas * 256
+    assert whole["bytes_landed"] == sum(a.nbytes for a in last.values())
+
+
+def test_delta_load_from_a_version_in_the_middle_of_the_chain(local):
+    """A held version that is itself a delta: only the versions after it
+    count, and the non-zero-copy path gives the same bytes."""
+    v0 = _state()
+    _publish(local, v0)
+    v2 = _chain(local, v0, 2, seed=1)
+    held, mid = _load(local)
+    assert mid["delta"] == 0
+    v5 = _chain(local, v2, 3, seed=2)
+    flat, loaded = _load(local, held=(mid["version"], held))
+    assert (loaded["delta"], loaded["versions"]) == (1, 3)
+    out = {}
+
+    def read(fs):
+        store = TensorStore(fs, prefix=ROOT)
+        out["flat"] = store.load("state", held=(mid["version"], held))
+        out["loaded"] = store.loaded
+
+    runtime_for(local).invoke(read, read_only=True)
+    assert out["loaded"]["delta"] == 1
+    for k in v5:
+        assert flat[k].tobytes() == out["flat"][k].tobytes() == v5[k].tobytes()
+
+
+def test_delta_load_falls_back_to_a_full_load(local):
+    """Every block is read again when the held arrays cannot stand for the
+    blocks left out: the held version is off the chain or behind a full
+    version, a leaf changed dtype or shape, or the save is in place."""
+    from repro.core.tensorstate import DELTA_CHAIN_MAX
+
+    v0 = _state()
+    _publish(local, v0)
+    held, first = _load(local)
+    h = (first["version"], held)
+
+    def full_again(expect):
+        flat, loaded = _load(local, held=h)
+        assert loaded["delta"] == 0 and loaded["leaves_kept"] == 0
+        assert loaded["bytes_landed"] == sum(a.nbytes for a in expect.values())
+        assert all(flat[k] is not held[k] for k in flat)
+        for k in expect:
+            assert flat[k].tobytes() == expect[k].tobytes()
+
+    # a version the served chain never passes
+    flat, loaded = _load(local, held=("state@0000000000000000", held))
+    assert loaded["delta"] == 0
+    # DELTA_CHAIN_MAX deltas still chain to the held version; the next
+    # publish is written full, and the chain ends there
+    t = _chain(local, v0, DELTA_CHAIN_MAX, seed=3)
+    assert _load(local, held=h)[1]["delta"] == 1
+    t = _chain(local, t, 1, seed=4)
+    full_again(t)
+    # a leaf of another dtype, then of another shape, in a delta
+    held, first = _load(local)
+    h = (first["version"], held)
+    for new in (dict(t, b=t["b"].astype(np.float64)),
+                dict(t, b=np.zeros(9, np.float32))):
+        stats = _publish(local, new, baseline=t)
+        assert stats["base"] is not None
+        full_again(new)
+        t = new
+    # an in-place save under the served name is rewritten in place
+    runtime_for(local).invoke(
+        lambda fs: TensorStore(fs, prefix=ROOT).save("state", t))
+    held, first = _load(local)
+    assert first["version"] == "state"
+    h = (first["version"], held)
+    full_again(t)
+
+
+def test_a_leaf_whose_blocks_are_all_dirty_lands_whole_with_no_copy(
+        local, monkeypatch):
+    from repro.core import obs
+
+    v0 = _state()
+    _publish(local, v0)
+    held, first = _load(local)
+    v1 = dict(v0, w=v0["w"] + 1.0, b=v0["b"].copy())
+    v1["b"][0] = 5.0
+    _publish(local, v1, baseline=v0)
+    ring = obs.SpanRecorder()
+    monkeypatch.setattr(obs, "SPANS", ring)
+    prev = obs.set_trace((obs.new_trace_id(), 1))
+    try:
+        flat, loaded = _load(local, held=(first["version"], held))
+    finally:
+        obs.set_trace(prev)
+    lands = {s["ar"]["bytes"]: s["ar"] for s in ring.spans()
+             if s["n"] == "tensor.land"}
+    assert lands[v1["w"].nbytes] == {"bytes": v1["w"].nbytes,
+                                     "landed": v1["w"].nbytes, "copied": 0}
+    assert lands[v1["b"].nbytes] == {"bytes": 32, "landed": 32, "copied": 0}
+    assert lands[v1["e"].nbytes] == {"bytes": v1["e"].nbytes,
+                                     "landed": 0, "copied": 0}
+    assert loaded["bytes_copied"] == 0 and loaded["leaves_kept"] == 1
+    for k in v1:
+        assert flat[k].tobytes() == v1[k].tobytes()
+
+
+def test_refresh_of_an_unmoved_pointer_keeps_every_leaf(local):
+    """A replica refreshing twice at one version lands nothing the second
+    time: every leaf is the array it already serves."""
+    from repro.serving.engine import SnapshotServer
+
+    v0 = _state()
+    _publish(local, v0)
+    replica = SnapshotServer(LocalServer(local.backend), lambda p, b: p,
+                             {"w": None, "b": None, "e": None}, root=ROOT)
+    replica.refresh()
+    first = dict(replica.params)
+    replica.refresh()
+    assert all(replica.params[k] is first[k] for k in first)
+    v1 = _chain(local, v0, 1, seed=5)
+    replica.refresh()
+    assert replica.params["w"] is not first["w"]
+    assert replica.params["b"] is first["b"] and replica.params["e"] is first["e"]
+    for k in v1:
+        assert replica.params[k].tobytes() == v1[k].tobytes()

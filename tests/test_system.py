@@ -189,3 +189,61 @@ def test_replica_records_refresh_upload_and_decode_spans_under_profiler(
     up, dec = (next(s for s in spans if s["n"] == n)
                for n in ("replica.upload", "replica.decode"))
     assert up["pa"] == dec["pa"] == 0 and up["ts"] + up["du"] <= dec["ts"]
+
+
+def test_replica_refresh_by_delta_records_what_it_landed(tiny_setup, monkeypatch):
+    """After a publish of ~1% of the blocks, the replica lands only those:
+    ``replica.refresh`` says ``delta`` 1 and the ``tensor.land`` spans'
+    ``landed`` sums to its ``bytes_landed``. A refresh of the same version
+    keeps every leaf, so the next request uploads nothing."""
+    from repro.core import obs
+    from repro.core.tensorstate import flatten_with_names, unflatten_like
+    from repro.launch.serve import make_decode_fn, publish
+
+    cfg, state0, _, _ = tiny_setup
+    ring = obs.SpanRecorder()
+    monkeypatch.setattr(obs, "SPANS", ring)
+    be = BackendService(block_size=256)
+    params = np_state(state0)["params"]
+    publish(LocalServer(be), params)
+    srv = SnapshotServer(LocalServer(be), make_decode_fn(cfg, 2),
+                         {"params": params})
+    prompts = np.zeros((2, 1), np.int32)
+    srv.refresh()
+    srv.serve(prompts)
+    old = dict(flatten_with_names({"params": params}))
+    state_bytes = sum(a.nbytes for a in old.values())
+    new = dict(old)
+    for name in sorted(old, key=lambda n: -old[n].nbytes)[:4]:
+        new[name] = old[name].copy()
+        new[name].reshape(-1)[0] += 1.0
+    publish(LocalServer(be), unflatten_like({"params": params}, new)["params"],
+            baseline=old)
+
+    def traced():
+        tid = obs.new_trace_id()
+        prev = obs.set_trace((tid, 1))
+        try:
+            srv.refresh()
+            srv.serve(prompts)
+        finally:
+            obs.set_trace(prev)
+        spans = ring.spans(tid)
+        one = lambda n: next(s for s in spans if s["n"] == n)  # noqa: E731
+        return (one("replica.refresh")["ar"], one("replica.upload")["ar"],
+                [s["ar"] for s in spans if s["n"] == "tensor.land"])
+
+    refresh, upload, lands = traced()
+    assert refresh["delta"] == 1 and refresh["versions"] == 1
+    assert 0 < refresh["bytes_landed"] < 0.05 * state_bytes
+    assert sum(a["landed"] for a in lands) == refresh["bytes_landed"]
+    assert sum(a["copied"] for a in lands) == refresh["bytes_copied"]
+    assert sum(a["bytes"] for a in lands) == state_bytes
+    assert refresh["leaves_kept"] == len(old) - 4
+    assert upload["bytes"] == state_bytes
+    served = dict(flatten_with_names(srv.params))
+    assert all(served[n].tobytes() == new[n].tobytes() for n in new)
+    refresh, upload, lands = traced()
+    assert (refresh["delta"], refresh["versions"], refresh["bytes_landed"],
+            refresh["leaves_kept"]) == (1, 0, 0, len(old))
+    assert upload["bytes"] == 0
